@@ -18,15 +18,14 @@ from repro.sharding.specs import (batch_shardings, cache_shardings, make_plan,
                                   opt_state_shardings, param_shardings)
 from repro.train.optimizer import adamw_init
 from repro.train.train_step import build_train_step
-from repro.utils import compat
-from repro.utils.compat import make_auto_mesh
+from repro.launch.mesh import make_auto_mesh
 
 mesh = make_auto_mesh((4, 2), ("data", "model"))
 cfg = get_config("llama3.2-1b").reduced()
 rng = np.random.RandomState(0)
 B, N = 8, 32
 
-with compat.set_mesh(mesh):
+with jax.sharding.set_mesh(mesh):
     for mode in (ExchangeMode.PRISM, ExchangeMode.VOLTAGE):
         plan = make_plan(mesh, cfg, mode, L=4, train=True)
         xcfg = plan.xcfg

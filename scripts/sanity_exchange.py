@@ -12,10 +12,10 @@ from repro.core.exchange import exchange_attention, decode_attention_sharded
 from repro.core.partition import (simulate_prism_attention,
                                   simulate_voltage_attention)
 from repro.core.prism_attention import reference_attention
+from repro.launch.mesh import make_auto_mesh
 from repro.transport import CodecSpec, codec_sim_attention
-from repro.utils import compat
 
-mesh = jax.make_mesh((4, 2), ("seq", "model"))
+mesh = make_auto_mesh((4, 2), ("seq", "model"))
 B, N, H, Hk, dh = 2, 64, 8, 4, 16
 L = 4
 rng = np.random.RandomState(0)
@@ -23,7 +23,7 @@ q = jnp.asarray(rng.randn(B, N, H, dh), jnp.float32)
 k = jnp.asarray(rng.randn(B, N, Hk, dh), jnp.float32)
 v = jnp.asarray(rng.randn(B, N, Hk, dh), jnp.float32)
 
-with compat.set_mesh(mesh):
+with jax.sharding.set_mesh(mesh):
     spec = NamedSharding(mesh, P(None, "seq", None, None))
     qs, ks, vs = (jax.device_put(x, spec) for x in (q, k, v))
 

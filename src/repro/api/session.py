@@ -212,8 +212,10 @@ class InferenceSession:
         fwd = registry.forward_fn(self.cfg)
         xcfg = plan.to_exchange_config()
         self.plans[key] = plan
-        self._execs[key] = jax.jit(
-            lambda batch: fwd(self.params, batch, xcfg)[0])
+        # params are an argument, not a closure: closed-over arrays would be
+        # baked into the program as constants (the whole model, per plan)
+        jitted = jax.jit(lambda params, batch: fwd(params, batch, xcfg)[0])
+        self._execs[key] = lambda batch: jitted(self.params, batch)
         return key
 
     def run(self, plan_key: str, batch_inputs: Any):

@@ -39,7 +39,6 @@ from repro.core.prism_attention import (
     reference_attention,
 )
 from repro.kernels import dispatch as kdsp
-from repro.utils import compat
 
 
 def all_gather_grad_safe(x: jnp.ndarray, axis_name: str, *, axis: int = 0,
@@ -109,33 +108,33 @@ def pin_activations(x: jnp.ndarray, cfg: ExchangeConfig) -> jnp.ndarray:
     boundaries so GSPMD never drifts into batch-replicated layouts."""
     if x.ndim < 2 or (not cfg.batch_axes and cfg.seq_axis is None):
         return x
-    if not compat.SHARDING_HINTS_SAFE:    # 0.4.x: hint can corrupt values
+    mesh = _current_mesh(cfg)
+    if mesh is None:
         return x
-    try:
-        mesh = compat.get_abstract_mesh()
-        if mesh is None or mesh.empty:
-            return x
-        bax = tuple(a for a in cfg.batch_axes if a in mesh.axis_names)
-        bsize = 1
-        for a in bax:
-            bsize *= mesh.shape[a]
-        b_spec = (bax if (bax and x.shape[0] % bsize == 0) else
-                  P.UNCONSTRAINED)
-        seq_ok = (cfg.seq_axis is not None and x.shape[1] > 1 and
-                  x.shape[1] % mesh.shape.get(cfg.seq_axis, 1) == 0)
-        s_spec = cfg.seq_axis if seq_ok else P.UNCONSTRAINED
-        spec = P(b_spec, s_spec, *([None] * (x.ndim - 2)))
-        return jax.lax.with_sharding_constraint(x, spec)
-    except (ValueError, RuntimeError, AttributeError, TypeError):
-        return x
+    bsize = 1
+    for a in cfg.batch_axes:
+        bsize *= mesh.shape[a]
+    b_spec = (cfg.batch_axes if (cfg.batch_axes and x.shape[0] % bsize == 0)
+              else P.UNCONSTRAINED)
+    seq_ok = (cfg.seq_axis is not None and x.shape[1] > 1 and
+              x.shape[1] % mesh.shape[cfg.seq_axis] == 0)
+    s_spec = cfg.seq_axis if seq_ok else P.UNCONSTRAINED
+    spec = P(b_spec, s_spec, *([None] * (x.ndim - 2)))
+    return jax.lax.with_sharding_constraint(x, spec)
 
 
-def _attn_local_block(q, k, v, part_idx, Np, *, causal, window, softcap, scale):
-    """Attention of local queries against gathered/global K/V."""
-    q_off = part_idx * Np
-    return reference_attention(
-        q, k, v, causal=causal, q_offset=q_off, kv_offset=0,
-        window=window, logit_softcap=softcap, scale=scale)
+def _current_mesh(cfg: ExchangeConfig):
+    """The mesh in context, or None when there is none (single-host use).
+    A mesh that lacks an axis ``cfg`` names is a configuration error."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return None
+    named = tuple(cfg.batch_axes) + ((cfg.seq_axis,) if cfg.seq_axis else ())
+    missing = [a for a in named if a not in mesh.axis_names]
+    if missing:
+        raise ValueError(f"mesh axes {mesh.axis_names} lack {missing}, "
+                         f"which the exchange config names")
+    return mesh
 
 
 def exchange_attention(
@@ -189,7 +188,7 @@ def prism_sim_prefill_attention(q, k, v, cfg, *, causal=False, window=None,
         raise NotImplementedError("PRISM_SIM with sliding window")
     return simulate_prism_attention(
         q, k, v, cfg.seq_shards, cfg.L, causal=causal,
-        logit_softcap=logit_softcap, scale=scale)
+        logit_softcap=logit_softcap, scale=scale, kv_mask=kv_mask)
 
 
 def voltage_prefill_attention(q, k, v, cfg, *, causal=False, window=None,
@@ -311,32 +310,33 @@ def prism_prefill_attention(q, k, v, cfg, *, causal=False, window=None,
 
 def _pin_seq_sharding(t: jnp.ndarray, axis: str) -> jnp.ndarray:
     """with_sharding_constraint: dim1 (sequence) on ``axis``, dim0 (batch)
-    left to propagation, all trailing dims replicated."""
-    U = P.UNCONSTRAINED
-    try:
-        spec = P(*([U] + [axis] + [None] * (t.ndim - 2)))
-        return jax.lax.with_sharding_constraint(t, spec)
-    except (ValueError, RuntimeError):
-        return t      # no mesh context (single-host tests)
+    left to propagation, all trailing dims replicated.  No-op without a
+    mesh (single-host tests); raises if the mesh has no ``axis``."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return t
+    if axis not in mesh.axis_names:
+        raise ValueError(f"sequence axis {axis!r} is not in the mesh "
+                         f"{mesh.axis_names}")
+    spec = P(P.UNCONSTRAINED, axis, *([None] * (t.ndim - 2)))
+    return jax.lax.with_sharding_constraint(t, spec)
 
 
 def _manual_batch_axes(batch: int, cfg: ExchangeConfig):
     """Batch axes to make manual in the exchange shard_map (device-local
-    view = the paper's per-device partition). Empty when indivisible so
-    small-batch tests keep working under GSPMD auto handling."""
+    view = the paper's per-device partition).  Empty without batch axes,
+    without a mesh, or when the batch does not divide over them (the
+    exchange then sees the whole batch on every device, e.g. global batch 1
+    on a data axis); raises when the mesh lacks one of them."""
     if not cfg.batch_axes:
         return ()
-    try:
-        mesh = compat.get_abstract_mesh()
-        if mesh is None or mesh.empty:
-            return ()
-        bax = tuple(a for a in cfg.batch_axes if a in mesh.axis_names)
-        size = 1
-        for a in bax:
-            size *= mesh.shape[a]
-        return bax if (bax and batch % size == 0) else ()
-    except (AttributeError, RuntimeError, TypeError):
+    mesh = _current_mesh(cfg)
+    if mesh is None:
         return ()
+    size = 1
+    for a in cfg.batch_axes:
+        size *= mesh.shape[a]
+    return tuple(cfg.batch_axes) if batch % size == 0 else ()
 
 
 def _seq_shard_map(fn, axis: str, n_masks: int = 0, batch_axes=()):
@@ -348,7 +348,7 @@ def _seq_shard_map(fn, axis: str, n_masks: int = 0, batch_axes=()):
     spec = P(b, axis, None, None)
     in_specs = (spec, spec, spec) + (P(b, axis),) * n_masks
     manual = set((axis,) + tuple(batch_axes))
-    return compat.shard_map(fn, in_specs=in_specs, out_specs=spec,
+    return jax.shard_map(fn, in_specs=in_specs, out_specs=spec,
                          axis_names=manual, check_vma=False)
 
 
@@ -390,7 +390,7 @@ def exchange_cross_attention(
                                        logit_softcap=logit_softcap, scale=scale)
         bax = _manual_batch_axes(q.shape[0], cfg) or None
         manual = {axis} | set(bax or ())
-        return compat.shard_map(
+        return jax.shard_map(
             volt,
             in_specs=(P(bax, axis, None, None), P(bax, axis, None, None),
                       P(bax, axis, None, None), P(bax, axis)),
@@ -410,7 +410,7 @@ def exchange_cross_attention(
                                     kv_mask=ms, mean_counts=cnt_all)
     bax = _manual_batch_axes(q.shape[0], cfg) or None
     manual = {axis} | set(bax or ())
-    return compat.shard_map(
+    return jax.shard_map(
         prism_x,
         in_specs=(P(bax, axis, None, None), P(bax, axis, None, None),
                   P(bax, axis, None, None), P(bax, axis)),
@@ -481,7 +481,7 @@ def exchange_attention_mla(
                                                q_offset=p * Np, scale=scale)
         bax = _manual_batch_axes(q.shape[0], cfg) or None
         manual = {axis} | set(bax or ())
-        return compat.shard_map(
+        return jax.shard_map(
             volt, in_specs=(P(bax, axis, None, None), P(bax, axis, None),
                             P(bax, axis, None)),
             out_specs=P(bax, axis, None, None),
@@ -504,7 +504,7 @@ def exchange_attention_mla(
                                     causal=causal, scale=scale)
     bax = _manual_batch_axes(q.shape[0], cfg) or None
     manual = {axis} | set(bax or ())
-    return compat.shard_map(
+    return jax.shard_map(
         prism_mla, in_specs=(P(bax, axis, None, None), P(bax, axis, None),
                              P(bax, axis, None)),
         out_specs=P(bax, axis, None, None),
@@ -561,7 +561,7 @@ def mla_decode_attention_sharded(
         o_g = jax.lax.psum(o_p, axis)
         return (o_g / l_g.transpose(0, 2, 1)[..., None]).astype(ql.dtype)
 
-    return compat.shard_map(
+    return jax.shard_map(
         shard_fn,
         in_specs=(P(None, None, None, None), P(None, None, None, None),
                   P(None, axis, None), P(None, axis, None)),
@@ -637,7 +637,7 @@ def decode_attention_sharded(
             mlog = _grouped_scores(qs, km_f) * scl
             mlog = _softcap(mlog, logit_softcap) + jnp.log(
                 jnp.asarray(seg, f32))
-            owner = jnp.repeat(jnp.arange(Pn), Lm)
+            owner = jnp.arange(Pn * Lm) // Lm
             mlog = jnp.where((owner != p)[None, None, None, :], mlog, NEG_INF)
             logits = jnp.concatenate([logits, mlog], axis=-1)
             # no collective: summaries already local
@@ -674,7 +674,7 @@ def decode_attention_sharded(
                              q.dtype) if k_means is None else k_means)
         v_means = (jnp.zeros((B0, Pn, 1, k_cache.shape[2], k_cache.shape[3]),
                              q.dtype) if v_means is None else v_means)
-    out = compat.shard_map(shard_fn, in_specs=in_specs, out_specs=q_spec,
+    out = jax.shard_map(shard_fn, in_specs=in_specs, out_specs=q_spec,
                         axis_names=manual, check_vma=False)(
         q, k_cache, v_cache, clen, k_means, v_means)
     return out

@@ -4,18 +4,17 @@ The paper's terminal device splits ``X ∈ R^{N×D}`` into ``P`` equal parts
 along the sequence dimension.  These helpers provide (a) the partitioning /
 reassembly math and (b) a *single-host simulation* of the P-device
 computation — the oracle the distributed (shard_map) implementation and the
-Pallas kernels are validated against, and the engine the edge latency
-simulator drives.
+Pallas kernels are validated against (off the TPU, where the dispatch layer
+runs the reference), and the engine the edge latency simulator drives.
 """
 from __future__ import annotations
 
 from typing import Callable, Optional
 
-import jax
 import jax.numpy as jnp
 
-from repro.core.prism_attention import prism_attention, reference_attention
-from repro.core.segment_means import segment_means
+from repro.core.prism_attention import reference_attention
+from repro.kernels import dispatch as kdsp
 
 
 def partition_sequence(x: jnp.ndarray, P: int, axis: int = 1) -> jnp.ndarray:
@@ -44,12 +43,18 @@ def simulate_prism_attention(
     causal: bool = False,
     logit_softcap: Optional[float] = None,
     scale: Optional[float] = None,
+    kv_mask: Optional[jnp.ndarray] = None,   # [B, N] bool; False → padding
 ) -> jnp.ndarray:
     """Single-host oracle of the P-device PRISM attention.
 
     Computes what every device p would produce (local full K/V + remote
     segment means, scaling-aware softmax) and concatenates the outputs back
-    into the full sequence.  Matches the shard_map implementation exactly.
+    into the full sequence.  Matches the shard_map implementation exactly,
+    padding included: with ``kv_mask`` the means are mask-aware (counts in
+    the scaling bias) and padded local keys are masked out.  Segment means
+    and attention go through the kernel-dispatch layer, as in the shard_map
+    path: the reference off the TPU, the Pallas kernels (and their counted
+    fallbacks) on it.
     """
     B, N, H, dh = q.shape
     Np = N // P
@@ -57,18 +62,33 @@ def simulate_prism_attention(
     qp = partition_sequence(q, P)     # [P, B, Np, H, dh]
     kp = partition_sequence(k, P)
     vp = partition_sequence(v, P)
-    # [P, B, L, Hk, dh] — means of *projected* K/V (linearity; no re-projection)
-    km = jax.vmap(lambda t: segment_means(t, L, axis=1))(kp)
-    vm = jax.vmap(lambda t: segment_means(t, L, axis=1))(vp)
-    km_all = km.transpose(1, 0, 2, 3, 4)   # [B, P, L, Hk, dh]
-    vm_all = vm.transpose(1, 0, 2, 3, 4)
+
+    def parts_as_batch(t):            # [P, B, ...] ↔ [P·B, ...]
+        return t.reshape(P * B, *t.shape[2:])
+
+    # means of *projected* K/V (linearity; no re-projection)
+    if kv_mask is None:
+        mp = cnt_all = None
+        km = kdsp.segment_means(parts_as_batch(kp), L, axis=1)
+        vm = kdsp.segment_means(parts_as_batch(vp), L, axis=1)
+    else:
+        mp = partition_sequence(kv_mask, P)                  # [P, B, Np]
+        km, cnt = kdsp.segment_means_masked(parts_as_batch(kp), L,
+                                            parts_as_batch(mp), axis=1)
+        vm, _ = kdsp.segment_means_masked(parts_as_batch(vp), L,
+                                          parts_as_batch(mp), axis=1)
+        cnt_all = cnt.reshape(P, B, L).transpose(1, 0, 2)    # [B, P, L]
+    km_all = km.reshape(P, B, *km.shape[1:]).transpose(1, 0, 2, 3, 4)
+    vm_all = vm.reshape(P, B, *vm.shape[1:]).transpose(1, 0, 2, 3, 4)
+    # km_all, vm_all: [B, P, L, Hk, dh]
 
     outs = []
     for p in range(P):
         outs.append(
-            prism_attention(
+            kdsp.prism_attention(
                 qp[p], kp[p], vp[p], km_all, vm_all, p, seg,
                 causal=causal, logit_softcap=logit_softcap, scale=scale,
+                kv_mask=None if mp is None else mp[p], mean_counts=cnt_all,
             )
         )
     return jnp.concatenate(outs, axis=1)

@@ -240,7 +240,7 @@ def prism_attention(
         logits_mean = logits_mean + jnp.log(jnp.maximum(counts, 1.0)
                                             )[:, None, None, :]
         nonempty = counts > 0
-    part_of_mean = jnp.repeat(jnp.arange(P), L)             # [P*L]
+    part_of_mean = jnp.arange(P * L) // L                   # [P*L]
     if causal:
         visible = part_of_mean < part_idx                   # strictly past
     else:

@@ -13,9 +13,12 @@ implementation directly.  Resolution order, first match wins:
    only run under ``interpret=True`` (correct but slow), so auto never
    selects them there; parity tests opt in explicitly.
 
-Every op degrades gracefully: shapes/arguments the kernel does not support
-(non-token segment axes, masked local keys in PRISM attention) silently use
-the reference path, so callers never need to special-case the backend.
+Shapes/arguments a kernel does not take (non-token segment axes, masked
+local keys in PRISM attention, head dims the compiled decode kernels cannot
+tile, PRISM partitions too long to hold in VMEM) run the reference instead, and every such fallback increments the
+counter ``kernels.fallback{op=...,reason=...}`` in :data:`METRICS`.  The
+routing runs while a function is traced, so the counter counts traces that
+fell back, not executions.
 """
 from __future__ import annotations
 
@@ -28,10 +31,14 @@ import jax.numpy as jnp
 
 from repro.core import prism_attention as ref_attn
 from repro.core import segment_means as ref_sm
+from repro.obs import MetricsRegistry
 
 _VALID = ("auto", "pallas", "reference")
 _OVERRIDE: Optional[str] = None
 ENV_VAR = "REPRO_KERNEL_BACKEND"
+
+#: Process-wide registry of the kernel-routing counters.
+METRICS = MetricsRegistry()
 
 
 def set_backend(name: Optional[str]) -> Optional[str]:
@@ -73,6 +80,27 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def _fallback(op: str, reason: str) -> None:
+    """Record that ``op`` was routed to the Pallas backend but ran the
+    reference because of ``reason``."""
+    METRICS.counter("kernels.fallback", {"op": op, "reason": reason}).inc()
+
+
+def fallback_counts() -> dict:
+    """``{"op/reason": count}`` of every reference fallback so far."""
+    return {f"{dict(m.labels)['op']}/{dict(m.labels)['reason']}":
+            int(m.value) for m in METRICS.find("kernels.fallback")}
+
+
+def _decode_tiles(k: jnp.ndarray) -> bool:
+    """Whether the compiled decode kernels can tile a [.., Hk, dh] cache:
+    each program reads one KV head's (tokens, dh) slab of the
+    [.., tokens, Hk·dh] view, so dh must be a lane multiple (128) unless
+    the slab is the whole row (Hk == 1).  Interpret mode takes any dh."""
+    Hk, dh = k.shape[-2:]
+    return _interpret() or dh % 128 == 0 or Hk == 1
+
+
 # ---------------------------------------------------------------------------
 # Segment Means (PRISM Eq. 1) — compression hot path
 # ---------------------------------------------------------------------------
@@ -84,10 +112,11 @@ def segment_means(x: jnp.ndarray, L: int, axis: int = -2) -> jnp.ndarray:
     every exchange call site); anything else falls back to the reference.
     """
     axis = axis % x.ndim
-    if (_use_pallas() and axis == 1 and x.ndim >= 3
-            and L > 0 and x.shape[1] % L == 0):
-        from repro.kernels.segment_means.ops import segment_means_op
-        return segment_means_op(x, L, interpret=_interpret())
+    if _use_pallas():
+        if axis == 1 and x.ndim >= 3 and L > 0 and x.shape[1] % L == 0:
+            from repro.kernels.segment_means.ops import segment_means_op
+            return segment_means_op(x, L, interpret=_interpret())
+        _fallback("segment_means", "layout")
     return ref_sm.segment_means(x, L, axis=axis)
 
 
@@ -100,19 +129,22 @@ def segment_means_masked(x: jnp.ndarray, L: int, mask: jnp.ndarray,
     reduction:  mean = (seg · kernel_mean(x·mask)) / max(count, 1).
     """
     axis = axis % x.ndim
-    if (_use_pallas() and axis == 1 and x.ndim >= 3
-            and L > 0 and x.shape[1] % L == 0 and mask.ndim == 2):
-        from repro.kernels.segment_means.ops import segment_means_op
-        B, N = x.shape[:2]
-        seg = N // L
-        mf = mask.astype(jnp.float32)
-        counts = mf.reshape(B, L, seg).sum(axis=-1)               # [B, L]
-        mx = x.astype(jnp.float32) * mf.reshape(
-            (B, N) + (1,) * (x.ndim - 2))
-        sums = segment_means_op(mx, L, interpret=_interpret()) * float(seg)
-        denom = jnp.maximum(counts, 1.0).reshape(
-            (B, L) + (1,) * (x.ndim - 2))
-        return (sums / denom).astype(x.dtype), counts
+    if _use_pallas():
+        if (axis == 1 and x.ndim >= 3 and L > 0 and x.shape[1] % L == 0
+                and mask.ndim == 2):
+            from repro.kernels.segment_means.ops import segment_means_op
+            B, N = x.shape[:2]
+            seg = N // L
+            mf = mask.astype(jnp.float32)
+            counts = mf.reshape(B, L, seg).sum(axis=-1)           # [B, L]
+            mx = x.astype(jnp.float32) * mf.reshape(
+                (B, N) + (1,) * (x.ndim - 2))
+            sums = (segment_means_op(mx, L, interpret=_interpret())
+                    * float(seg))
+            denom = jnp.maximum(counts, 1.0).reshape(
+                (B, L) + (1,) * (x.ndim - 2))
+            return (sums / denom).astype(x.dtype), counts
+        _fallback("segment_means_masked", "layout")
     return ref_sm.segment_means_masked(x, L, mask, axis=axis)
 
 
@@ -136,13 +168,15 @@ def decode_attention(q: jnp.ndarray,        # [B, 1, H, dh]
     locally (the single-shard degenerate of the cross-shard LSE merge).
     """
     if _use_pallas():
-        from repro.kernels.flash_decode.ops import flash_decode_op
-        o, m, l = flash_decode_op(q, k_cache, v_cache, cache_len,
-                                  offset=offset, window=window, scale=scale,
-                                  softcap=logit_softcap,
-                                  interpret=_interpret())
-        out = o / jnp.maximum(l, 1e-38)[..., None]                # [B, H, dh]
-        return out[:, None].astype(q.dtype)                       # [B,1,H,dh]
+        if _decode_tiles(k_cache):
+            from repro.kernels.flash_decode.ops import flash_decode_op
+            o, m, l = flash_decode_op(q, k_cache, v_cache, cache_len,
+                                      offset=offset, window=window,
+                                      scale=scale, softcap=logit_softcap,
+                                      interpret=_interpret())
+            out = o / jnp.maximum(l, 1e-38)[..., None]            # [B, H, dh]
+            return out[:, None].astype(q.dtype)                   # [B,1,H,dh]
+        _fallback("decode_attention", "head_dim")
     from repro.kernels.flash_decode.ops import validity_mask
     valid = validity_mask(q.shape[0], k_cache.shape[1], cache_len,
                           offset=offset, window=window)
@@ -169,13 +203,15 @@ def decode_attention_paged(q: jnp.ndarray,           # [B, 1, H, dh]
     prefetched table — no gather is ever materialized.
     """
     if _use_pallas():
-        from repro.kernels.flash_decode.paged import flash_decode_paged_op
-        o, m, l = flash_decode_paged_op(q, k_pool, v_pool, page_table,
-                                        cache_len, scale=scale,
-                                        softcap=logit_softcap,
-                                        interpret=_interpret())
-        out = o / jnp.maximum(l, 1e-38)[..., None]                # [B, H, dh]
-        return out[:, None].astype(q.dtype)                       # [B,1,H,dh]
+        if _decode_tiles(k_pool):
+            from repro.kernels.flash_decode.paged import flash_decode_paged_op
+            o, m, l = flash_decode_paged_op(q, k_pool, v_pool, page_table,
+                                            cache_len, scale=scale,
+                                            softcap=logit_softcap,
+                                            interpret=_interpret())
+            out = o / jnp.maximum(l, 1e-38)[..., None]            # [B, H, dh]
+            return out[:, None].astype(q.dtype)                   # [B,1,H,dh]
+        _fallback("decode_attention_paged", "head_dim")
     from repro.kernels.flash_decode.ops import validity_mask
     from repro.kernels.flash_decode.paged import gather_pages
     k = gather_pages(k_pool, page_table)
@@ -198,16 +234,27 @@ def prism_attention(q, k_local, v_local, k_means, v_means, part_idx,
                     q_offset=0) -> jnp.ndarray:
     """Scaling-aware softmax attention (see ``repro.core.prism_attention``).
 
-    The kernel supports unpadded local keys and a static q-offset of 0; the
-    padded / chunk-recursed cases use the reference.
+    The kernel supports unpadded local keys, a static q-offset of 0, and
+    partitions whose K/V fit in VMEM whole; the padded / chunk-recursed /
+    longer cases use the reference.
     """
-    if (_use_pallas() and kv_mask is None
-            and isinstance(q_offset, int) and q_offset == 0):
-        from repro.kernels.prism_attention.ops import prism_attention_op
-        return prism_attention_op(
-            q, k_local, v_local, k_means, v_means, part_idx, seg_size,
-            causal=causal, scale=scale, softcap=logit_softcap,
-            mean_counts=mean_counts, interpret=_interpret())
+    if _use_pallas():
+        from repro.kernels.prism_attention.ops import (fits_vmem,
+                                                       prism_attention_op)
+        M = k_means.shape[1] * k_means.shape[2]
+        if kv_mask is not None:
+            _fallback("prism_attention", "kv_mask")
+        elif not (isinstance(q_offset, int) and q_offset == 0):
+            _fallback("prism_attention", "q_offset")
+        elif not (_interpret() or fits_vmem(
+                q.shape[1], k_local.shape[1], M, q.shape[-1],
+                max(q.dtype.itemsize, k_local.dtype.itemsize))):
+            _fallback("prism_attention", "vmem")
+        else:
+            return prism_attention_op(
+                q, k_local, v_local, k_means, v_means, part_idx, seg_size,
+                causal=causal, scale=scale, softcap=logit_softcap,
+                mean_counts=mean_counts, interpret=_interpret())
     return ref_attn.prism_attention(
         q, k_local, v_local, k_means, v_means, part_idx, seg_size,
         causal=causal, logit_softcap=logit_softcap, scale=scale,

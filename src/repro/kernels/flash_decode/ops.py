@@ -1,5 +1,6 @@
-"""jit'd wrapper: builds the validity bias from (cache_len, offset, window)
-and merges shard partials (the exact LSE combine used across devices)."""
+"""jit'd wrapper: builds each row's valid span from (cache_len, offset,
+window) and merges shard partials (the exact LSE combine used across
+devices)."""
 from __future__ import annotations
 
 import functools
@@ -17,32 +18,41 @@ def _on_cpu() -> bool:
 
 @functools.lru_cache(maxsize=None)
 def pick_s_block(S: int) -> int:
-    """Largest power-of-two tile (≤512) dividing ``S``.  Cached per S — the
-    divisor search used to rerun on every trace of ``flash_decode_op``, and
-    the paged op shares the same selection for its page-size tiles."""
-    if S % 512 == 0:
-        return 512
-    return max(t for t in (256, 128, 64, 32, 16, 8, 4, 2, 1) if S % t == 0)
+    """Largest power-of-two tile (8..512) dividing ``S``, else ``S`` whole.
+
+    A TPU block's sublane dim must be a multiple of 8 or the full dim, so
+    the search never goes below 8.  Cached per S — the divisor search used
+    to rerun on every trace of ``flash_decode_op``."""
+    for t in (512, 256, 128, 64, 32, 16, 8):
+        if S % t == 0:
+            return t
+    return S
+
+
+def valid_span(B: int, cache_len, offset=0,
+               window: Optional[int] = None) -> jnp.ndarray:
+    """[B, 2] int32: each row's valid cache slots of this shard, as local
+    positions [lo, hi) — below the (global) ``cache_len`` and inside the
+    sliding window.  The ONE definition of cache validity: the kernels
+    compare against it, and ``validity_mask`` (the reference) expands it."""
+    hi = jnp.broadcast_to(jnp.reshape(jnp.asarray(cache_len, jnp.int32),
+                                      (-1,)), (B,)) - offset
+    lo = jnp.zeros_like(hi) if window is None else hi - window
+    return jnp.stack([lo, hi], axis=-1).astype(jnp.int32)
 
 
 def validity_mask(B: int, S: int, cache_len, offset=0,
                   window: Optional[int] = None) -> jnp.ndarray:
-    """[B, S] bool: True where the (global) position is a valid cache slot
-    and inside the sliding window.  The ONE definition of cache validity —
-    the kernel bias and the reference fallback both derive from it."""
-    gpos = offset + jnp.arange(S)[None, :]
-    clen = jnp.broadcast_to(jnp.reshape(jnp.asarray(cache_len), (-1, 1)),
-                            (B, 1))
-    ok = gpos < clen
-    if window is not None:
-        ok &= gpos >= clen - window
-    return ok
+    """[B, S] bool: True where the slot is in the row's ``valid_span``."""
+    span = valid_span(B, cache_len, offset=offset, window=window)
+    pos = jnp.arange(S)[None, :]
+    return (pos >= span[:, :1]) & (pos < span[:, 1:])
 
 
 def validity_bias(B: int, S: int, cache_len, offset=0,
                   window: Optional[int] = None) -> jnp.ndarray:
     """[B, S] additive bias: 0 where valid, -inf where empty / outside the
-    sliding window."""
+    sliding window (the input of the ``flash_decode_ref`` oracle)."""
     ok = validity_mask(B, S, cache_len, offset=offset, window=window)
     return jnp.where(ok, 0.0, NEG_INF).astype(jnp.float32)
 
@@ -61,10 +71,10 @@ def flash_decode_op(q: jnp.ndarray,      # [B, 1, H, dh] or [B, H, dh]
     squeeze = q.ndim == 4
     if squeeze:
         q = q[:, 0]
-    B, H, dh = q.shape
+    B = q.shape[0]
     S = k.shape[1]
-    bias = validity_bias(B, S, cache_len, offset=offset, window=window)
-    return flash_decode_pallas(q, k, v, bias, scale=scale, softcap=softcap,
+    span = valid_span(B, cache_len, offset=offset, window=window)
+    return flash_decode_pallas(q, k, v, span, scale=scale, softcap=softcap,
                                s_block=pick_s_block(S), interpret=interpret)
 
 

@@ -15,15 +15,36 @@ from repro.kernels.prism_attention.kernel import (NEG_INF,
                                                   prism_attention_pallas)
 
 
+#: The TPU compiler's default scoped-VMEM limit for one kernel on v5e.
+VMEM_LIMIT = 16 * 2**20
+
+
 def _on_cpu() -> bool:
     return jax.default_backend() == "cpu"
+
+
+def pick_q_block(Nq: int) -> int:
+    """Largest power-of-two q tile (8..128) dividing ``Nq``, else ``Nq``
+    whole: a TPU block's sublane dim is a multiple of 8 or the full dim."""
+    return next((t for t in (128, 64, 32, 16, 8) if Nq % t == 0), Nq)
+
+
+def fits_vmem(Nq: int, Nk: int, M: int, dh: int, itemsize: int) -> bool:
+    """Whether one program's VMEM stays under :data:`VMEM_LIMIT`: the
+    double-buffered q/out tiles and the whole local K/V and mean K/V, plus
+    one f32 score tile [TQ, Nk + M].  A little above the compiler's own
+    count: for a v5e at TQ=128, dh=128, bf16, Nk=12288 it reported
+    18.00 MiB where this gives 18.15 MiB."""
+    tq = pick_q_block(Nq)
+    operands = 2 * itemsize * dh * (2 * tq + 2 * (Nk + M))
+    return operands + 4 * tq * (Nk + M) <= VMEM_LIMIT
 
 
 def build_mean_bias(B: int, P: int, L: int, part_idx, seg_size: int,
                     *, causal: bool,
                     mean_counts: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """[B, P·L] additive bias: log(count) for visible means, -inf else."""
-    part_of_mean = jnp.repeat(jnp.arange(P), L)            # [P*L]
+    part_of_mean = jnp.arange(P * L) // L                  # [P*L]
     if causal:
         visible = part_of_mean < part_idx
     else:
@@ -60,8 +81,6 @@ def prism_attention_op(
     vm = v_means.reshape(B, P * L, *v_means.shape[3:])
     bias = build_mean_bias(B, P, L, part_idx, seg_size, causal=causal,
                            mean_counts=mean_counts)
-    q_block = 128 if Nq % 128 == 0 else (
-        max(t for t in (64, 32, 16, 8, 4, 2, 1) if Nq % t == 0))
     return prism_attention_pallas(
         q, k_loc, v_loc, km, vm, bias, causal=causal, scale=scale,
-        softcap=softcap, q_block=q_block, interpret=interpret)
+        softcap=softcap, q_block=pick_q_block(Nq), interpret=interpret)

@@ -117,8 +117,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
     from repro.utils.tree import param_bytes, param_count
     n_params = param_count(aparams)
 
-    from repro.utils.compat import set_mesh as _set_mesh
-    with _set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         if shape.kind == "train":
             from repro.sharding.specs import _param_gb
             mdt = jnp.bfloat16 if _param_gb(cfg) > 100 else jnp.float32

@@ -368,6 +368,8 @@ def main():
                     help="dump the unified metrics registries "
                          "(Prometheus text format) at exit")
     args = ap.parse_args()
+    from repro.utils.compile_cache import configure_compile_cache
+    configure_compile_cache()
     if args.rpc:
         _rpc_main(args)
     elif args.real:

@@ -4,7 +4,13 @@ from __future__ import annotations
 
 import jax
 
-from repro.utils.compat import make_auto_mesh
+
+def make_auto_mesh(shape, names):
+    """``jax.make_mesh`` with every axis explicitly Auto (GSPMD-propagated),
+    which is what the exchange's sharding constraints and shard_maps ask
+    for."""
+    return jax.make_mesh(tuple(shape), tuple(names),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

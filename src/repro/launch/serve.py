@@ -5,15 +5,17 @@
         [--slo-ms 5000] [--slots 4] [--chunk 8] [--tokens 16] \
         [--bandwidth 400] [--objective latency|energy] \
         [--pages 64 --page-size 16]   # paged KV mode (prefix caching on)
+        [--full]                      # published widths (default: reduced)
 
 The hand-rolled per-token decode loop is gone: requests flow through the
 bounded queue → adaptive scheduler (micro-batches formed from the compiled
 policy table at ``--bandwidth``/``--objective``) → continuous-batching
 slot-pool decode (the compiled ``lax.scan`` fast path).  ``--mode local`` /
 ``--mode prism`` pin the executable family; ``--mode adaptive`` lets the
-policy route.  Legacy flags (``--devices --batch --prompt-len --L``) keep
-working: ``--batch`` sizes the slot pool and doubles as the default request
-count.
+policy route.  Legacy flags (``--batch --prompt-len --L``) keep working:
+``--batch`` sizes the slot pool and doubles as the default request count.
+Without ``--full`` the model is the reduced smoke variant (vocabulary 512),
+which is what a CPU run can afford; ``--full`` serves the published config.
 
 NOTE: PRISM here runs in its single-host simulation form (``prism_sim`` —
 same math, unpartitioned tensors); the serving slot pool is not
@@ -21,17 +23,6 @@ mesh-sharded yet.  Genuinely sequence-sharded decode over a device mesh is
 exercised by ``scripts/sanity_e2e_distributed.py`` and ``launch/dryrun.py``.
 """
 import argparse
-import os
-
-if __name__ == "__main__":
-    _ap = argparse.ArgumentParser()
-    _ap.add_argument("--devices", type=int, default=8)
-    _args, _ = _ap.parse_known_args()
-    os.environ.setdefault(
-        "XLA_FLAGS",
-        f"--xla_force_host_platform_device_count={_args.devices} "
-        "--xla_disable_hlo_passes=all-reduce-promotion")
-
 import time
 
 import numpy as np
@@ -42,7 +33,6 @@ def main():
     ap.add_argument("--arch", default="llama3.2-1b")
     ap.add_argument("--mode", default="prism",
                     choices=["prism", "local", "adaptive"])
-    ap.add_argument("--devices", type=int, default=8)   # legacy (XLA flag)
     ap.add_argument("--batch", type=int, default=8,
                     help="slot-pool size (legacy: batch width)")
     ap.add_argument("--prompt-len", type=int, default=16)
@@ -82,6 +72,9 @@ def main():
                     help="paged mode: quantize prefix-cache pages idle for "
                          "this many admissions (LOSSY; 0 = never)")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--full", action="store_true",
+                    help="serve the published config (default: the reduced "
+                         "smoke variant with a 512-token vocabulary)")
     ap.add_argument("--trace", default="", metavar="PATH",
                     help="write the request span trace as JSONL to PATH "
                          "and print a per-stage breakdown at exit")
@@ -90,6 +83,8 @@ def main():
                          "(Prometheus text format) at exit")
     args = ap.parse_args()
 
+    from repro.utils.compile_cache import configure_compile_cache
+    configure_compile_cache()
     from repro.api import ExecutionPlan, InferenceSession
     from repro.serving import ServingRuntime
 
@@ -110,7 +105,8 @@ def main():
                                        args.codec).default_param))
         codecs = (args.codec,)
     session = InferenceSession.from_config(
-        args.arch, reduced={"vocab_size": 512}, plans=plans,
+        args.arch, reduced=False if args.full else {"vocab_size": 512},
+        plans=plans,
         objective=args.objective, allow_modes=allow,
         initial_bandwidth_mbps=args.bandwidth)
     from repro.profiling import SweepSpec

@@ -49,7 +49,7 @@ def main():
     from repro.train.train_step import build_train_step
 
     n_model = 2 if args.devices >= 4 else 1
-    from repro.utils.compat import make_auto_mesh
+    from repro.launch.mesh import make_auto_mesh
     mesh = make_auto_mesh((args.devices // n_model, n_model),
                           ("data", "model"))
     cfg = get_config(args.arch)
@@ -57,8 +57,7 @@ def main():
         cfg = cfg.reduced(vocab_size=512)
     plan = make_plan(mesh, cfg, ExchangeMode(args.mode), L=args.L, train=True)
 
-    from repro.utils.compat import set_mesh as _set_mesh
-    with _set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         params = registry.init_params(cfg, seed=0)
         pshard = param_shardings(plan, cfg, params)
         params = jax.device_put(params, pshard)

@@ -58,11 +58,8 @@ def _pin_expert(t: jnp.ndarray) -> jnp.ndarray:
     keeping E sharded — 10 GB/device for DeepSeek-V2. Pinning the
     activation side forces the expert-parallel schedule."""
     try:
-        from repro.utils import compat
-        if not compat.SHARDING_HINTS_SAFE:   # 0.4.x: hint can corrupt values
-            return t
-        mesh = compat.get_abstract_mesh()
-        if (mesh is None or mesh.empty or "model" not in mesh.axis_names
+        mesh = jax.sharding.get_abstract_mesh()
+        if (mesh.empty or "model" not in mesh.axis_names
                 or t.shape[1] % mesh.shape["model"]):
             return t
         from jax.sharding import PartitionSpec as P

@@ -599,6 +599,15 @@ def _scan_decode_layers(body_fn, x, params_stack, cache_stack):
     return x, cache_stack
 
 
+def _zeros_in_memory(tree):
+    """Keep a zero-filled cache's zeros.  Made inside a program and filled
+    by the prefill's layer scan only up to the prompt, the cache is
+    otherwise allocated uninitialized by the TPU compiler (libtpu 0.0.34
+    sinks the zero broadcast into the scan), and the positions past the
+    prompt come back holding garbage, NaN included."""
+    return jax.lax.optimization_barrier(tree)
+
+
 def init_decode_cache(cfg: ModelConfig, batch: int, seq: int) -> Params:
     """Cache pytree with stacked leading layer/group dims (scan layout)."""
     dtype = cfg.jdtype
@@ -607,7 +616,8 @@ def init_decode_cache(cfg: ModelConfig, batch: int, seq: int) -> Params:
     def kv(n, s):
         c = init_kv_cache(batch, s, cfg.n_kv_heads, cfg.hd, dtype,
                           quant=cfg.kv_quant)
-        return jax.tree_util.tree_map(lambda l: jnp.stack([l] * n), c)
+        return _zeros_in_memory(
+            jax.tree_util.tree_map(lambda l: jnp.stack([l] * n), c))
 
     if fam == "dense":
         if cfg.local_global:
@@ -619,7 +629,8 @@ def init_decode_cache(cfg: ModelConfig, batch: int, seq: int) -> Params:
         if cfg.mla is not None:
             def mlac(n):
                 c = mla_mod.init_mla_cache(batch, seq, cfg.mla, dtype)
-                return jax.tree_util.tree_map(lambda l: jnp.stack([l] * n), c)
+                return _zeros_in_memory(jax.tree_util.tree_map(
+                    lambda l: jnp.stack([l] * n), c))
             return {"first": mlac(fd), "kv": mlac(cfg.n_layers - fd)}
         return {"first": kv(fd, seq), "kv": kv(cfg.n_layers - fd, seq)}
     if fam == "audio":

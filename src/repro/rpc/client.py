@@ -63,6 +63,25 @@ from repro.serving.engine import Completion
 from repro.serving.queue import Request, RequestQueue
 
 
+def require_cpu_children(env: Dict[str, str]) -> None:
+    """Refuse to spawn JAX worker processes that would need an accelerator.
+
+    Every worker builds its own ``InferenceSession``, and a chip belongs
+    to one process: on a TPU host the first child would take it and the
+    rest would fail or hang.  Children pinned to the CPU
+    (``JAX_PLATFORMS=cpu`` in their environment) are fine anywhere."""
+    if env.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return
+    import jax
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"RPC workers are subprocesses that each open the JAX backend; "
+            f"on this {backend} host they would contend for one chip. Run "
+            "the RPC fleet with JAX_PLATFORMS=cpu, or serve in one process "
+            "(ServingRuntime, or launch.fleet --real).")
+
+
 class RpcWorker(Worker):
     """A process-boundary fleet worker (spawned subprocess or remote addr).
 
@@ -174,6 +193,7 @@ class RpcWorker(Worker):
                "--prism-cr", str(a["prism_cr"])]
         env = dict(os.environ)
         env["PYTHONUNBUFFERED"] = "1"
+        require_cpu_children(env)
         self.proc = subprocess.Popen(cmd, stdout=subprocess.PIPE, text=True,
                                      env=env)
         deadline = time.monotonic() + self.connect_timeout_s

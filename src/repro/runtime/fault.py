@@ -201,10 +201,13 @@ class FaultTolerantLoop:
                                               step))
                 if self.on_failure is not None:
                     self.on_failure(dead)
-                # restore from newest checkpoint and resume
+                # restore from newest checkpoint and resume; an async save
+                # still being written would otherwise land between reading
+                # the newest step and restoring it
+                self.ckpt.wait()
                 latest = self.ckpt.latest
                 if latest is not None:
-                    state = self.ckpt.restore(state)
+                    state = self.ckpt.restore(state, step=latest)
                     step = latest
                 for n in dead:       # controller replaces / drops the node
                     self.monitor.revive(n)

@@ -82,11 +82,7 @@ def _placeholder_keys(n: int):
     row's key before any decode reads it), so one cached array per size is
     safe to share: jax arrays are immutable and the pools only ever
     functionally replace the whole vector."""
-    base = jax.random.key(0)
-    try:
-        return jnp.broadcast_to(base, (n,))
-    except Exception:                  # older jax: key arrays can't broadcast
-        return jnp.stack([base] * n)
+    return jnp.broadcast_to(jax.random.key(0), (n,))
 
 
 @dataclasses.dataclass

@@ -30,11 +30,8 @@ def _pin_vocab(t: jnp.ndarray, xcfg: ExchangeConfig) -> jnp.ndarray:
         return t
     try:
         from jax.sharding import PartitionSpec as P
-        from repro.utils import compat
-        if not compat.SHARDING_HINTS_SAFE:   # 0.4.x: hint can corrupt values
-            return t
-        mesh = compat.get_abstract_mesh()
-        if mesh is None or mesh.empty:
+        mesh = jax.sharding.get_abstract_mesh()
+        if mesh.empty:
             return t
         vax = next((a for a in xcfg.batch_axes[::-1]
                     if a in mesh.axis_names

@@ -316,3 +316,41 @@ def test_legacy_shims_removed():
         from repro.serving import AdaptiveDispatcher  # noqa: F401
     with pytest.raises(ImportError):
         from repro.serving.dispatcher import AdaptiveDispatcher  # noqa: F401,F811
+
+
+def test_plan_executables_take_params_as_an_argument(session):
+    """The per-plan executables read ``session.params`` at call time: the
+    parameters are an argument of the compiled program, not constants
+    baked into it (which would copy the whole model into every program)."""
+    import jax
+    batch = {"tokens": jnp.ones((1, 8), jnp.int32)}
+    before = np.asarray(session.run("local", batch))
+    params = session.params
+    try:
+        session.params = jax.tree_util.tree_map(lambda p: p * 2, params)
+        after = np.asarray(session.run("local", batch))
+    finally:
+        session.params = params
+    assert not np.allclose(before, after)
+    np.testing.assert_array_equal(np.asarray(session.run("local", batch)),
+                                  before)
+
+
+def test_compile_cache_env_wins_else_fixed_checkout_dir(monkeypatch):
+    import jax
+    from repro.utils import compile_cache as cc
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv(cc.ENV_VAR, "/elsewhere/cache")
+        assert cc.configure_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir is None  # JAX's own
+        monkeypatch.delenv(cc.ENV_VAR)
+        path = cc.configure_compile_cache()
+        assert path == str(cc.CHECKOUT_CACHE)
+        assert jax.config.jax_compilation_cache_dir == path
+        root = cc.CHECKOUT_CACHE.parent
+        assert (root / "src" / "repro").is_dir()
+        assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

@@ -90,11 +90,17 @@ def test_segment_means_masked_empty_segment():
     np.testing.assert_allclose(np.asarray(am), np.asarray(bm), atol=3e-6)
 
 
+def _fallbacks(name):
+    return kdsp.fallback_counts().get(name, 0)
+
+
 def test_segment_means_non_token_axis_falls_back():
-    """Axes the kernel can't tile still work (reference route)."""
+    """Axes the kernel can't tile still work (reference route), counted."""
     x = jnp.asarray(RNG.randn(2, 3, 12, 8), jnp.float32)
+    before = _fallbacks("segment_means/layout")
     with kdsp.force_backend("pallas"):
         out = kdsp.segment_means(x, 4, axis=2)
+    assert _fallbacks("segment_means/layout") == before + 1
     from repro.core.segment_means import segment_means as ref
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref(x, 4, axis=2)),
                                atol=1e-6)
@@ -169,6 +175,57 @@ def test_prism_attention_masked_falls_back():
     km = jnp.asarray(RNG.randn(B, P, L, H, dh), jnp.float32)
     vm = jnp.asarray(RNG.randn(B, P, L, H, dh), jnp.float32)
     mask = jnp.asarray([[True] * 6 + [False] * 2])
+    before = _fallbacks("prism_attention/kv_mask")
     a, b = _pair(kdsp.prism_attention, q, kl, vl, km, vm, 0, 4,
                  kv_mask=mask)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert _fallbacks("prism_attention/kv_mask") == before + 1
+
+
+@pytest.mark.parametrize("masked", [False, True])
+def test_prism_sim_routes_through_dispatch(masked):
+    """The single-host PRISM simulation (the ``prism_sim`` plan) takes the
+    kernels where they apply and counts a fallback per partition where they
+    do not (padded keys), with the reference's numbers either way."""
+    from repro.core.partition import simulate_prism_attention
+    B, N, H, Hk, dh, P, L = 2, 32, 4, 2, 16, 4, 2
+    q = jnp.asarray(RNG.randn(B, N, H, dh), jnp.float32)
+    k = jnp.asarray(RNG.randn(B, N, Hk, dh), jnp.float32)
+    v = jnp.asarray(RNG.randn(B, N, Hk, dh), jnp.float32)
+    mask = (jnp.broadcast_to(jnp.arange(N) < 27, (B, N)) if masked
+            else None)
+    before = _fallbacks("prism_attention/kv_mask")
+    a, b = _pair(simulate_prism_attention, q, k, v, P, L, kv_mask=mask)
+    assert _fallbacks("prism_attention/kv_mask") == before + (P if masked
+                                                              else 0)
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-5)
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_decode_head_dim_the_chip_cannot_tile_falls_back(monkeypatch, paged):
+    """Compiled decode kernels read one KV head's (tokens, dh) slab, so a
+    head dim that is not a lane multiple (with Hk > 1) runs the reference
+    and is counted; interpret mode keeps taking any head dim."""
+    B, S, H, Hk, dh, ps = 2, 32, 4, 2, 16, 8
+    q = jnp.asarray(RNG.randn(B, 1, H, dh), jnp.float32)
+    clen = jnp.asarray([20, 32])
+    if paged:
+        op, name = kdsp.decode_attention_paged, "decode_attention_paged"
+        k = jnp.asarray(RNG.randn(B * S // ps, ps, Hk, dh), jnp.float32)
+        v = jnp.asarray(RNG.randn(B * S // ps, ps, Hk, dh), jnp.float32)
+        args = (q, k, v, jnp.arange(B * S // ps).reshape(B, S // ps), clen)
+    else:
+        op, name = kdsp.decode_attention, "decode_attention"
+        k = jnp.asarray(RNG.randn(B, S, Hk, dh), jnp.float32)
+        v = jnp.asarray(RNG.randn(B, S, Hk, dh), jnp.float32)
+        args = (q, k, v, clen)
+    with kdsp.force_backend("reference"):
+        want = op(*args)
+    before = _fallbacks(f"{name}/head_dim")
+    with kdsp.force_backend("pallas"):
+        op(*args)                                   # interpret: kernel
+        assert _fallbacks(f"{name}/head_dim") == before
+        monkeypatch.setattr(kdsp, "_interpret", lambda: False)
+        got = op(*args)                             # "compiled": reference
+    assert _fallbacks(f"{name}/head_dim") == before + 1
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -32,3 +32,33 @@ def test_e2e_distributed_train_and_decode():
     r = _run("scripts/sanity_e2e_distributed.py")
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
     assert "E2E DISTRIBUTED SANITY PASSED" in r.stdout
+
+
+def test_exchange_sharding_helpers_raise_on_a_mesh_they_cannot_use():
+    """The only quiet case is no mesh at all; a mesh without the named
+    axes is an error.  A batch that does not divide over the batch axes is
+    not: the exchange keeps it whole on every device."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import AxisType
+    from repro.core import exchange as xchg
+    from repro.launch.mesh import make_auto_mesh
+
+    t = jnp.zeros((3, 4, 2, 8))
+    cfg = xchg.ExchangeConfig(xchg.ExchangeMode.PRISM, "seq", 1, L=2,
+                              batch_axes=("data",))
+    assert xchg._pin_seq_sharding(t, "seq") is t
+    assert xchg._manual_batch_axes(3, cfg) == ()
+    with jax.sharding.set_mesh(make_auto_mesh((1,), ("model",))):
+        with pytest.raises(ValueError, match="not in the mesh"):
+            xchg._pin_seq_sharding(t, "seq")
+        with pytest.raises(ValueError, match="lack"):
+            xchg._manual_batch_axes(3, cfg)
+    with jax.sharding.set_mesh(make_auto_mesh((1, 1), ("data", "seq"))):
+        assert xchg._manual_batch_axes(3, cfg) == ("data",)
+        jax.jit(lambda x: xchg._pin_seq_sharding(x, "seq"))(t)
+    two = jax.sharding.AbstractMesh((2, 1), ("data", "seq"),
+                                    axis_types=(AxisType.Auto,) * 2)
+    with jax.sharding.use_abstract_mesh(two):
+        assert xchg._manual_batch_axes(3, cfg) == ()
+        assert xchg._manual_batch_axes(4, cfg) == ("data",)

@@ -72,12 +72,11 @@ def test_cross_pod_mean_subprocess():
         out, res = compressed_cross_pod_mean(gin, None, L=8, pod_axis="pod")
         return out
 
-    from repro.utils.compat import make_auto_mesh
+    from repro.launch.mesh import make_auto_mesh
     mesh = make_auto_mesh((1,), ("pod",))
     from jax.sharding import PartitionSpec as P
-    from repro.utils import compat
-    with compat.set_mesh(mesh):
-        out = compat.shard_map(f, in_specs=({"w": P(None, None)},),
+    with jax.sharding.set_mesh(mesh):
+        out = jax.shard_map(f, in_specs=({"w": P(None, None)},),
                             out_specs={"w": P(None, None)},
                             axis_names={"pod"}, check_vma=False)(g)
     np.testing.assert_allclose(np.asarray(out["w"]), np.asarray(g["w"]),

@@ -6,7 +6,8 @@ import numpy as np
 import pytest
 
 from repro.kernels.flash_decode import flash_decode_op, flash_decode_ref
-from repro.kernels.flash_decode.ops import merge_partials, validity_bias
+from repro.kernels.flash_decode.ops import (merge_partials, validity_bias,
+                                           validity_mask)
 from repro.kernels.prism_attention import (prism_attention_op,
                                            prism_attention_ref)
 from repro.kernels.prism_attention.ops import build_mean_bias
@@ -122,6 +123,46 @@ def test_flash_decode_window():
     full = reference_attention(q[:, None], k, v, kv_mask=mask)[:, 0]
     np.testing.assert_allclose(np.asarray(o / l[..., None]),
                                np.asarray(full), atol=3e-5)
+
+
+@pytest.mark.parametrize("window", [None, 16])
+def test_flash_decode_ignores_garbage_outside_valid_slots(window):
+    """Cache slots outside the valid range may hold anything (a compiled
+    prefill has left NaN past the prompt): the kernel selects them away in
+    K and V, so its output equals the one over zeros there."""
+    B, S, H, Hk, dh = 2, 64, 4, 2, 16
+    q = jnp.asarray(RNG.randn(B, H, dh), jnp.float32)
+    k = jnp.asarray(RNG.randn(B, S, Hk, dh), jnp.float32)
+    v = jnp.asarray(RNG.randn(B, S, Hk, dh), jnp.float32)
+    clen = jnp.asarray([20, 50])
+    ok = validity_mask(B, S, clen, window=window)[:, :, None, None]
+    clean = flash_decode_op(q, jnp.where(ok, k, 0.0), jnp.where(ok, v, 0.0),
+                            clen, window=window)
+    dirty = flash_decode_op(q, jnp.where(ok, k, jnp.nan),
+                            jnp.where(ok, v, jnp.nan), clen, window=window)
+    for a, b in zip(dirty, clean):
+        assert np.all(np.isfinite(np.asarray(a)))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
+
+
+def test_flash_decode_paged_ignores_garbage_outside_valid_slots():
+    from repro.kernels.flash_decode import flash_decode_paged_op
+    B, ps, MP, H, Hk, dh = 2, 8, 4, 4, 2, 16
+    q = jnp.asarray(RNG.randn(B, H, dh), jnp.float32)
+    pt = jnp.arange(B * MP, dtype=jnp.int32).reshape(B, MP)
+    clen = jnp.asarray([11, 25])
+    ok = validity_mask(B, MP * ps, clen).reshape(B * MP, ps)[..., None, None]
+    k = jnp.asarray(RNG.randn(B * MP, ps, Hk, dh), jnp.float32)
+    v = jnp.asarray(RNG.randn(B * MP, ps, Hk, dh), jnp.float32)
+    clean = flash_decode_paged_op(q, jnp.where(ok, k, 0.0),
+                                  jnp.where(ok, v, 0.0), pt, clen,
+                                  interpret=True)
+    dirty = flash_decode_paged_op(q, jnp.where(ok, k, jnp.nan),
+                                  jnp.where(ok, v, jnp.nan), pt, clen,
+                                  interpret=True)
+    for a, b in zip(dirty, clean):
+        assert np.all(np.isfinite(np.asarray(a)))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
 
 
 def test_flash_decode_merge_shards():

@@ -41,6 +41,27 @@ def test_prism_seg1_equals_full_bidirectional():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
+def test_prism_sim_key_mask_excludes_padding():
+    """With a key mask the simulation never reads padded keys (the shard_map
+    path's semantics): changing the pads moves no real output, a full mask
+    equals no mask, and segment size 1 is still exact full attention."""
+    q, k, v = _qkv(N=32)
+    mask = jnp.broadcast_to(jnp.arange(32) < 27, (2, 32))
+    out = simulate_prism_attention(q, k, v, P=4, L=8, kv_mask=mask)
+    junk = jnp.where(mask[..., None, None], k, 1e3)
+    alt = simulate_prism_attention(q, junk, jnp.where(
+        mask[..., None, None], v, -1e3), P=4, L=8, kv_mask=mask)
+    np.testing.assert_allclose(np.asarray(out[:, :27]),
+                               np.asarray(alt[:, :27]), atol=2e-5)
+    ref = reference_attention(q, k, v, kv_mask=mask)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    full = simulate_prism_attention(q, k, v, P=4, L=2,
+                                    kv_mask=jnp.ones((2, 32), bool))
+    np.testing.assert_allclose(
+        np.asarray(full),
+        np.asarray(simulate_prism_attention(q, k, v, P=4, L=2)), atol=2e-5)
+
+
 def test_prism_causal_first_partition_is_local_only():
     """Partition 0 under causality sees no remote means — equals local-only
     causal attention on its slice."""

@@ -447,3 +447,19 @@ def test_rpc_readmit_respawns_process(rpc_fleet):
     assert done[0].worker == "w1"
     np.testing.assert_array_equal(np.asarray(done[0].tokens),
                                   _oracle(ref, req))
+
+
+def test_workers_refuse_to_share_an_accelerator(monkeypatch):
+    """On an accelerator host every spawned worker would open the one chip:
+    spawning fails at once, before any process starts, unless the workers
+    are pinned to the CPU."""
+    import jax
+    from repro.rpc import client
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(client.subprocess, "Popen", None)   # never reached
+    with pytest.raises(RuntimeError, match="contend for one chip"):
+        client.require_cpu_children({"JAX_PLATFORMS": ""})
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with pytest.raises(RuntimeError, match="contend for one chip"):
+        RpcWorker("w-tpu", spawn=True)
+    client.require_cpu_children({"JAX_PLATFORMS": "cpu"})
