@@ -49,8 +49,13 @@ STAGES = (
     "queue_wait",     # arrival -> admission into a slot/page pool
     "prefill",        # prompt pass priming a slot (prime_slot)
     "admit",          # KV install + slot bookkeeping (admit_slot)
-    "decode",         # admission -> completion residency of one request
+    "first_token",    # admission done -> first chunk emission of a request
+    "decode",         # first emission -> completion of one request
+    "admit_pass",     # one step's whole admission pass (lookups, dispatches)
     "decode_chunk",   # one continuous-batching chunk (all active rows)
+    "chunk_dispatch",  # chunk host prep (page growth, tables), dispatch
+    "chunk_wait",     # host blocked on the chunk's tokens (and queued work)
+    "chunk_emit",     # token bookkeeping + on_progress after the chunk
     "codec_encode",   # exchange-codec encode (client->wire)
     "codec_decode",   # exchange-codec decode (wire->device)
     "staging",        # host<->device copy of a staged link (modeled)
@@ -318,7 +323,7 @@ def request_breakdown(spans: Sequence[Span], trace_id: str
                       ) -> Dict[str, float]:
     """Per-request stage decomposition for one trace.  The leaf stages
     of a request tree partition its wall time (queue_wait + prefill +
-    admit + decode ≈ finished − arrival), so ``sum(values)`` reconciles
-    with the request's measured latency — the BENCH_trace gate asserts
-    this within 10%."""
+    admit + first_token + decode ≈ finished − arrival), so
+    ``sum(values)`` reconciles with the request's measured latency — the
+    BENCH_trace gate asserts this within 10%."""
     return breakdown([s for s in spans if s.trace_id == trace_id])

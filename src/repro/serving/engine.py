@@ -95,6 +95,9 @@ class Completion:
     admitted_ts: float
     finished_ts: float
     slo_ms: Optional[float] = None
+    # runtime clock at the first chunk emission carrying this request's
+    # tokens (what a streaming client sees as its first token)
+    first_token_ts: Optional[float] = None
     extrapolated: bool = False         # scheduled off the profiled grid
     codec: str = ""                    # exchange codec of the serving plan
     wire_bytes: int = 0                # modeled bytes-on-wire, this request
@@ -132,6 +135,7 @@ class _Active:
     codec: str = ""                        # exchange codec of the plan
     wire_bytes: int = 0                    # modeled per-request wire bytes
     decode_start: float = 0.0              # tracer stamp: admission done
+    first_emit_ts: Optional[float] = None  # first chunk emission (runtime clock)
 
     @property
     def emitted(self) -> int:
@@ -162,6 +166,8 @@ class SlotPool:
         self.max_len = max_len
         self.tracer = None                 # set by ServingRuntime._pool
         self.trace_worker = ""
+        self.clock = time.monotonic        # the runtime's, set with tracer
+        self.last_chunk: Optional[Dict[str, Any]] = None   # traced only
         self.cache = session.init_slot_pool(n_slots, max_len)
         self.tok = jnp.zeros((n_slots,), jnp.int32)
         self.lengths = jnp.zeros((n_slots,), jnp.int32)
@@ -228,7 +234,10 @@ class SlotPool:
 
     def decode_chunk(self, n_steps: int) -> float:
         """One chunk over all slots; appends tokens to active requests and
-        returns the wall ms the chunk took (straggler signal)."""
+        returns the wall ms the chunk took (straggler signal).  With a
+        tracer, ``last_chunk`` holds the chunk's phase stamps and counts
+        (see :func:`chunk_record`)."""
+        traced = self.tracer is not None
         t0 = time.perf_counter()
         toks, self.cache, self.lengths, self.keys = \
             self.session.decode_chunk(self.cache, self.tok, self.lengths,
@@ -236,14 +245,34 @@ class SlotPool:
                                       n_steps=n_steps, plan=self.plan,
                                       max_len=self.max_len)
         self.tok = toks[:, -1]
+        waited = self.clock() if traced else 0.0
         out = np.asarray(toks)
         wall_ms = 1e3 * (time.perf_counter() - t0)
+        if traced:
+            self.last_chunk = chunk_record(self, n_steps, waited,
+                                           self.clock())
         for i, act in enumerate(self.slots):
             if act is None or act.done:
                 continue
             need = act.request.n_new - act.emitted
             act.tokens.extend(int(t) for t in out[i, :need])
         return wall_ms
+
+
+def chunk_record(pool, n_steps: int, waited: float, emitted: float
+                 ) -> Dict[str, Any]:
+    """A traced chunk's host phase boundaries (runtime clock) and counts,
+    taken after its tokens reached the host and before they are handed
+    out: ``waited`` ends the dispatch, ``emitted`` ends the wait;
+    ``rows_busy`` of ``n_rows`` rows held a request, ``tokens`` is what
+    the chunk's steps add to those requests (the prefill's first token
+    excluded)."""
+    busy = [act for act in pool.slots if act is not None]
+    return {"waited": waited, "emitted": emitted,
+            "counts": {"rows_busy": len(busy), "n_rows": len(pool.slots),
+                       "tokens": sum(max(min(act.request.n_new
+                                             - act.emitted, n_steps), 0)
+                                     for act in busy)}}
 
 
 class ServingRuntime:
@@ -327,9 +356,19 @@ class ServingRuntime:
         self._req_spans: Dict[int, Any] = {}   # open per-request root spans
         self._requeue_ts: Dict[int, float] = {}
         # hot-path handles: resolved once, not per chunk/completion
-        self._chunk_hist = self.metrics.histogram("serving.chunk_ms")
+        self._chunk_hist = self.metrics.histogram(
+            "serving.chunk_ms",
+            help="host wall ms of a decode chunk's dispatch and wait, for "
+                 "chunks with no admission dispatched ahead of them in the "
+                 "same step (that wait would include the admission's "
+                 "device work)")
         self._latency_hist = self.metrics.histogram(
             "serving.request_latency_ms")
+        self._ttft_hist = self.metrics.histogram(
+            "serving.ttft_ms", help="arrival -> first chunk emission")
+        self._tpot_hist = self.metrics.histogram(
+            "serving.tpot_ms",
+            help="(last emission - first emission) / (n_new - 1)")
         self.stats = StatsDict(
             self.metrics, "serving",
             {"steps": 0, "chunks": 0, "admitted": 0,
@@ -375,6 +414,7 @@ class ServingRuntime:
             self.pools[key] = pool
         pool.tracer = self.tracer        # may be attached after pools exist
         pool.trace_worker = self.trace_worker
+        pool.clock = self.clock
         return pool
 
     def _run_trace(self) -> str:
@@ -420,7 +460,7 @@ class ServingRuntime:
                 "prefix_hits": 0, "prefix_misses": 0, "full_hits": 0,
                 "partial_hits": 0, "cow_splits": 0, "cold_pages": 0,
                 "dequant_pages": 0, "prefix_entries": 0,
-                "prefix_evictions": 0, "admit_ms": 0.0}
+                "prefix_evictions": 0}
             for p in self.pools.values():
                 for k, v in p.page_stats().items():
                     if k in agg:
@@ -454,7 +494,10 @@ class ServingRuntime:
         self.stats["steps"] += 1
         now = self.clock()
         self._check_faults(now)
+        admitted = self.stats["admitted"]
         self._admit(now)
+        # a chunk's wait also covers admission work dispatched before it
+        quiet = self.stats["admitted"] == admitted
         done: List[Completion] = []
         tr = self.tracer
         for key, pool in self.pools.items():
@@ -478,6 +521,10 @@ class ServingRuntime:
                     self.stats["straggled"] += 1
             t0 = self.clock()
             wall_ms = pool.decode_chunk(self.chunk)
+            emitted = self.clock()
+            for act in pool.slots:
+                if act is not None and act.first_emit_ts is None:
+                    act.first_emit_ts = emitted
             self.stats["chunks"] += 1
             self._observe_stragglers(pool, wall_ms * straggle)
             if self.on_progress is not None:
@@ -486,11 +533,9 @@ class ServingRuntime:
                         self.on_progress(act.request.id, act.tokens)
             fin = self.clock()
             if tr is not None:
-                tr.record("decode_chunk", start=t0, end=fin, kind="serving",
-                          trace_id=self._run_trace(),
-                          worker=self.trace_worker, plan=key,
-                          active=pool.n_active, steps=self.chunk)
-                self._chunk_hist.observe(wall_ms)
+                self._record_chunk(pool, key, t0, fin)
+                if quiet:
+                    self._chunk_hist.observe(wall_ms)
             for i, act in enumerate(pool.slots):
                 if act is not None and act.done:
                     pool.evict(i)
@@ -501,26 +546,55 @@ class ServingRuntime:
                         admitted_ts=act.admitted_ts, finished_ts=fin,
                         slo_ms=act.request.slo_ms,
                         extrapolated=act.extrapolated,
-                        codec=act.codec, wire_bytes=act.wire_bytes))
+                        codec=act.codec, wire_bytes=act.wire_bytes,
+                        first_token_ts=act.first_emit_ts))
                     if tr is not None:
-                        self._finish_request(act, fin)
+                        self._finish_request(act, emitted, fin)
         self.completions.extend(done)
         return done
 
-    def _finish_request(self, act: _Active, fin: float) -> None:
-        """Close a finished request's trace: one ``decode`` residency leaf
-        (admission-complete → finished) plus the root ``request`` span."""
+    def _record_chunk(self, pool, key: str, start: float, fin: float
+                      ) -> None:
+        """The ``decode_chunk`` span with the pool's counts, and its three
+        host phases as children: ``chunk_dispatch`` (page growth, table
+        upload, the call), ``chunk_wait`` (blocked on the tokens) and
+        ``chunk_emit`` (bookkeeping and ``on_progress``)."""
+        c = pool.last_chunk
+        sp = self.tracer.record(
+            "decode_chunk", start=start, end=fin, kind="serving",
+            trace_id=self._run_trace(), worker=self.trace_worker, plan=key,
+            steps=self.chunk, **c["counts"])
+        for name, a, b in (("chunk_dispatch", start, c["waited"]),
+                           ("chunk_wait", c["waited"], c["emitted"]),
+                           ("chunk_emit", c["emitted"], fin)):
+            self.tracer.record(name, start=a, end=b, kind="serving",
+                               trace_id=sp.trace_id, parent_id=sp.span_id,
+                               worker=self.trace_worker)
+
+    def _finish_request(self, act: _Active, last: float, fin: float
+                        ) -> None:
+        """Close a finished request's trace: a ``first_token`` leaf
+        (admission done → first emission), a ``decode`` leaf (→ finished)
+        and the root ``request`` span.  ``last`` is the emission that
+        delivered its last tokens."""
         req = act.request
         root = self._req_spans.pop(req.id, None)
         tid = req.trace_id or request_trace_id(req.id)
+        parent = root.span_id if root is not None else None
+        first = act.first_emit_ts
         self.tracer.record(
-            "decode", start=act.decode_start or act.admitted_ts,
-            end=fin, kind="serving", trace_id=tid,
-            parent_id=root.span_id if root is not None else None,
-            worker=self.trace_worker, tokens=req.n_new)
+            "first_token", start=act.decode_start or act.admitted_ts,
+            end=first, kind="serving", trace_id=tid, parent_id=parent,
+            worker=self.trace_worker)
+        self.tracer.record(
+            "decode", start=first, end=fin, kind="serving", trace_id=tid,
+            parent_id=parent, worker=self.trace_worker, tokens=req.n_new)
         if root is not None:
             self.tracer.finish(root, at=fin)
         self._latency_hist.observe(1e3 * (fin - req.arrival_ts))
+        self._ttft_hist.observe(1e3 * (first - req.arrival_ts))
+        if req.n_new > 1:
+            self._tpot_hist.observe(1e3 * (last - first) / (req.n_new - 1))
 
     def run(self, max_steps: int = 100_000) -> List[Completion]:
         """Serve until the queue and every pool are empty."""
@@ -606,6 +680,18 @@ class ServingRuntime:
         return k
 
     def _admit(self, now: float) -> Optional[MicroBatch]:
+        tr = self.tracer
+        start = self.clock() if tr is not None else 0.0
+        mb = self._admit_pass(now)
+        if tr is not None and mb is not None:
+            # recorded, not opened: the per-request spans the pass makes
+            # belong to each request's own tree, not under this one
+            tr.record("admit_pass", start=start, end=self.clock(),
+                      kind="serving", trace_id=self._run_trace(),
+                      worker=self.trace_worker, requests=len(mb.requests))
+        return mb
+
+    def _admit_pass(self, now: float) -> Optional[MicroBatch]:
         free = self._free_slots()
         if self.paged:
             # admit against free *pages*, not free rows: the policy table's
@@ -635,14 +721,14 @@ class ServingRuntime:
                 tr.record("queue_wait",
                           start=self._requeue_ts.pop(req.id,
                                                      req.arrival_ts),
-                          end=tr.clock(), kind="serving",
+                          end=self.clock(), kind="serving",
                           trace_id=req.trace_id,
                           parent_id=root.span_id, worker=self.trace_worker)
             with tr.active(root) if tr is not None else _NULL_CTX:
                 act = pool.admit(req, slot, mb.exec_key, mb.extrapolated,
                                  now)
             if tr is not None:
-                act.decode_start = tr.clock()
+                act.decode_start = self.clock()
             self.stats["admitted"] += 1
             self.stats["wire_bytes"] += act.wire_bytes
         overflow = mb.requests[len(free_ids):]

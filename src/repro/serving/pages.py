@@ -347,11 +347,11 @@ class PagedPool:
         self.slots: List[Optional[Any]] = [None] * n_rows
         self.tracer = None                 # set by ServingRuntime._pool
         self.trace_worker = ""
+        self.clock = time.monotonic        # the runtime's, set with tracer
+        self.last_chunk: Optional[Dict[str, Any]] = None   # traced only
         self.stats = {"prefix_hits": 0, "prefix_misses": 0, "full_hits": 0,
                       "partial_hits": 0, "cow_splits": 0, "cold_pages": 0,
-                      "dequant_pages": 0, "admit_ms": 0.0}
-        # benchmarks flip this on to charge prefill to admission wall time
-        self.time_admits = False
+                      "dequant_pages": 0}
         # pages the in-flight admit alloc'd/retained (rollback journal)
         self._acquired: List[int] = []
 
@@ -402,7 +402,6 @@ class PagedPool:
                 f"request needs {req.total_len} positions but the page "
                 f"table is sized for {self.max_pages * ps}; raise "
                 "ServingRuntime(max_len=)")
-        t0 = time.perf_counter()
         prompt = np.asarray(req.prompt, np.int32).reshape(-1)
         T0 = int(prompt.shape[0])
         P0 = -(-T0 // ps)
@@ -463,9 +462,6 @@ class PagedPool:
                           codec=(self.plan.effective_codec if wire else ""),
                           wire_bytes=wire)
             self.slots[slot] = act
-            if self.time_admits:
-                jax.block_until_ready(self.tok)
-                self.stats["admit_ms"] += 1e3 * (time.perf_counter() - t0)
         except BaseException:
             self._rollback_admit(slot, reserved, committed0)
             raise
@@ -635,7 +631,12 @@ class PagedPool:
 
     def decode_chunk(self, n_steps: int) -> float:
         """One chunk over all rows; appends tokens to active requests and
-        returns the wall ms the chunk took (straggler signal)."""
+        returns the wall ms the chunk took (straggler signal).  With a
+        tracer, ``last_chunk`` holds the chunk's phase stamps and counts
+        (``engine.chunk_record``, plus ``pages_valid``: distinct pages
+        holding valid tokens of busy rows after the chunk, and
+        ``pages_held``: pages off the free list)."""
+        traced = self.tracer is not None
         t0 = time.perf_counter()
         for row, act in enumerate(self.slots):
             if act is not None:
@@ -649,8 +650,12 @@ class PagedPool:
                 self.tok, self.lengths, self.keys, self.temps,
                 n_steps=n_steps, plan=self.plan)
         self.tok = toks[:, -1]
+        waited = self.clock() if traced else 0.0
         out = np.asarray(toks)
         wall_ms = 1e3 * (time.perf_counter() - t0)
+        if traced:
+            from repro.serving.engine import chunk_record
+            rec = chunk_record(self, n_steps, waited, self.clock())
         for i, act in enumerate(self.slots):
             if act is None:
                 continue
@@ -659,6 +664,16 @@ class PagedPool:
                 continue
             need = act.request.n_new - act.emitted
             act.tokens.extend(int(t) for t in out[i, :need])
+        if traced:
+            valid = set()
+            for row, act in enumerate(self.slots):
+                if act is not None:
+                    valid.update(
+                        self.row_pages[row][:-(-self.row_len[row] // ps)])
+            rec["counts"].update(
+                pages_valid=len(valid),
+                pages_held=self.n_pages - len(self.alloc.free))
+            self.last_chunk = rec
         return wall_ms
 
     # -- cold pages (lossy; off unless cold_horizon is set) ------------------
