@@ -92,13 +92,15 @@ def fallback_counts() -> dict:
             int(m.value) for m in METRICS.find("kernels.fallback")}
 
 
-def _decode_tiles(k: jnp.ndarray) -> bool:
+def _decode_tiles(k: jnp.ndarray, whole_rows: bool = False) -> bool:
     """Whether the compiled decode kernels can tile a [.., Hk, dh] cache:
     each program reads one KV head's (tokens, dh) slab of the
     [.., tokens, Hk·dh] view, so dh must be a lane multiple (128) unless
-    the slab is the whole row (Hk == 1).  Interpret mode takes any dh."""
+    the slab is the whole row (Hk == 1).  The paged kernel copies whole
+    rows (``whole_rows``) and slices each head's lanes in VMEM, so there
+    dh must be a lane multiple always.  Interpret mode takes any dh."""
     Hk, dh = k.shape[-2:]
-    return _interpret() or dh % 128 == 0 or Hk == 1
+    return _interpret() or dh % 128 == 0 or (Hk == 1 and not whole_rows)
 
 
 # ---------------------------------------------------------------------------
@@ -199,11 +201,11 @@ def decode_attention_paged(q: jnp.ndarray,           # [B, 1, H, dh]
 
     Reference path: materialize the gather with ``jnp.take`` and run the
     exact dense reference (CPU/interpret parity oracle).  Pallas path: the
-    paged flash-decode kernel indexes pool pages through the scalar-
-    prefetched table — no gather is ever materialized.
+    paged flash-decode kernel copies each row's valid pages through the
+    scalar-prefetched table — no gather is ever materialized.
     """
     if _use_pallas():
-        if _decode_tiles(k_pool):
+        if _decode_tiles(k_pool, whole_rows=True):
             from repro.kernels.flash_decode.paged import flash_decode_paged_op
             o, m, l = flash_decode_paged_op(q, k_pool, v_pool, page_table,
                                             cache_len, scale=scale,

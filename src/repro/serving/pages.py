@@ -634,8 +634,9 @@ class PagedPool:
         returns the wall ms the chunk took (straggler signal).  With a
         tracer, ``last_chunk`` holds the chunk's phase stamps and counts
         (``engine.chunk_record``, plus ``pages_valid``: distinct pages
-        holding valid tokens of busy rows after the chunk, and
-        ``pages_held``: pages off the free list)."""
+        holding valid tokens of busy rows after the chunk, ``pages_held``:
+        pages off the free list, and ``kv_blocks_walked`` of
+        ``kv_blocks_table``: the paged kernel's blocks at the last step)."""
         traced = self.tracer is not None
         t0 = time.perf_counter()
         for row, act in enumerate(self.slots):
@@ -656,6 +657,9 @@ class PagedPool:
         if traced:
             from repro.serving.engine import chunk_record
             rec = chunk_record(self, n_steps, waited, self.clock())
+            # each row's attended length at the chunk's last step
+            last = np.minimum(np.asarray(self.row_len) + n_steps - 1,
+                              caps) + 1
         for i, act in enumerate(self.slots):
             if act is None:
                 continue
@@ -672,9 +676,22 @@ class PagedPool:
                         self.row_pages[row][:-(-self.row_len[row] // ps)])
             rec["counts"].update(
                 pages_valid=len(valid),
-                pages_held=self.n_pages - len(self.alloc.free))
+                pages_held=self.n_pages - len(self.alloc.free),
+                **self._kv_blocks(last))
             self.last_chunk = rec
         return wall_ms
+
+    def _kv_blocks(self, lengths: np.ndarray) -> Dict[str, int]:
+        """Blocks the paged decode kernel walks for rows of ``lengths``
+        (``kv_blocks_walked``), and the blocks of the whole table
+        (``kv_blocks_table``)."""
+        from repro.kernels.flash_decode.paged import pages_per_block
+        k = self.pool["kv"]["k"]
+        ppb = pages_per_block(self.page_size, k.shape[-2] * k.shape[-1],
+                              self.max_pages, k.dtype.itemsize)
+        bk = ppb * self.page_size
+        return {"kv_blocks_walked": int(sum(-(-lengths // bk))),
+                "kv_blocks_table": self.n_rows * -(-self.max_pages // ppb)}
 
     # -- cold pages (lossy; off unless cold_horizon is set) ------------------
 

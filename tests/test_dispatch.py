@@ -229,3 +229,24 @@ def test_decode_head_dim_the_chip_cannot_tile_falls_back(monkeypatch, paged):
         got = op(*args)                             # "compiled": reference
     assert _fallbacks(f"{name}/head_dim") == before + 1
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_paged_decode_one_kv_head_needs_a_lane_multiple(monkeypatch):
+    """The paged kernel copies whole [ps, Hk·dh] pages and slices each
+    head's lanes, so even one KV head of dh 64 runs the reference when
+    compiled (the dense kernel takes it whole), and is counted."""
+    B, ps, MP, H, dh = 2, 8, 4, 4, 64
+    q = jnp.asarray(RNG.randn(B, 1, H, dh), jnp.float32)
+    k = jnp.asarray(RNG.randn(B * MP, ps, 1, dh), jnp.float32)
+    v = jnp.asarray(RNG.randn(B * MP, ps, 1, dh), jnp.float32)
+    pt = jnp.arange(B * MP).reshape(B, MP)
+    clen = jnp.asarray([9, 32])
+    monkeypatch.setattr(kdsp, "_interpret", lambda: False)
+    assert kdsp._decode_tiles(k) and not kdsp._decode_tiles(k, True)
+    before = _fallbacks("decode_attention_paged/head_dim")
+    with kdsp.force_backend("pallas"):
+        got = kdsp.decode_attention_paged(q, k, v, pt, clen)
+    assert _fallbacks("decode_attention_paged/head_dim") == before + 1
+    with kdsp.force_backend("reference"):
+        want = kdsp.decode_attention_paged(q, k, v, pt, clen)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -146,14 +146,21 @@ def test_flash_decode_ignores_garbage_outside_valid_slots(window):
 
 
 def test_flash_decode_paged_ignores_garbage_outside_valid_slots():
+    """NaN past each row's length inside its last page, in pages the
+    table names past the walk (one all-NaN page), and so in the VMEM
+    block the kernel reuses: none of it counts.  Blocks of 16 pages."""
     from repro.kernels.flash_decode import flash_decode_paged_op
-    B, ps, MP, H, Hk, dh = 2, 8, 4, 4, 2, 16
+    B, ps, MP, H, Hk, dh = 2, 16, 20, 16, 8, 128
     q = jnp.asarray(RNG.randn(B, H, dh), jnp.float32)
-    pt = jnp.arange(B * MP, dtype=jnp.int32).reshape(B, MP)
-    clen = jnp.asarray([11, 25])
-    ok = validity_mask(B, MP * ps, clen).reshape(B * MP, ps)[..., None, None]
-    k = jnp.asarray(RNG.randn(B * MP, ps, Hk, dh), jnp.float32)
-    v = jnp.asarray(RNG.randn(B * MP, ps, Hk, dh), jnp.float32)
+    clen = jnp.asarray([11, 300])
+    walked = -(-clen // ps)
+    pt = jnp.where(jnp.arange(MP)[None] < walked[:, None],
+                   jnp.arange(B * MP).reshape(B, MP), B * MP)
+    ok = jnp.concatenate([
+        validity_mask(B, MP * ps, clen).reshape(B * MP, ps),
+        jnp.zeros((1, ps), bool)])[..., None, None]
+    k = jnp.asarray(RNG.randn(B * MP + 1, ps, Hk, dh), jnp.float32)
+    v = jnp.asarray(RNG.randn(B * MP + 1, ps, Hk, dh), jnp.float32)
     clean = flash_decode_paged_op(q, jnp.where(ok, k, 0.0),
                                   jnp.where(ok, v, 0.0), pt, clen,
                                   interpret=True)
@@ -187,23 +194,47 @@ def test_flash_decode_merge_shards():
 
 # --- paged flash decode (page table via scalar prefetch) --------------------
 
-@pytest.mark.parametrize("B,P,ps,MP,H,Hk,dh",
-                         [(2, 9, 16, 4, 4, 2, 16), (3, 13, 8, 3, 6, 3, 32),
-                          (1, 5, 32, 2, 8, 1, 64)])
+def _paged_case(*shape, lens="random"):
+    return pytest.param(*shape, lens, id="-".join(map(str, shape))
+                        + ("" if lens == "random" else f"-{lens}"))
+
+
+@pytest.mark.parametrize("B,P,ps,MP,H,Hk,dh,lens", [
+    _paged_case(2, 9, 16, 4, 4, 2, 16), _paged_case(3, 13, 8, 3, 6, 3, 32),
+    _paged_case(1, 5, 32, 2, 8, 1, 64),
+    # internlm2-1.8b widths: blocks of 16 (f32) or 32 (bf16) pages, so a
+    # 40-page table holds 3 or 2 blocks, the last one short
+    _paged_case(4, 161, 16, 40, 16, 8, 128, lens="edges"),
+    _paged_case(1, 81, 16, 80, 16, 8, 128),               # the suffix scan
+    _paged_case(3, 121, 16, 40, 16, 8, 128, lens="trash"),
+])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_decode_paged_matches_gather_reference(B, P, ps, MP, H, Hk,
-                                                     dh, dtype):
-    """Pallas paged kernel (page table as block index map through scalar
-    prefetch) vs the jnp.take gather + dense reference, on random page
-    tables with repeated pages and ragged valid lengths."""
+                                                     dh, lens, dtype):
+    """Pallas paged kernel (page table and lengths through scalar
+    prefetch, pages copied a block at a time) vs the jnp.take gather +
+    dense reference, on random page tables with repeated pages.  Lengths:
+    ragged at random; or ``edges``, one row each of 1, one block, one
+    block + 1 and the whole table; or ``trash``, a row whose whole table
+    points at one page, as an idle row's points at the trash page, beside
+    a row whose length runs past its table."""
     from repro.kernels.flash_decode import (flash_decode_paged_op,
                                             flash_decode_paged_ref,
                                             gather_pages)
+    from repro.kernels.flash_decode.paged import pages_per_block
     q = jnp.asarray(RNG.randn(B, H, dh), dtype)
     kp = jnp.asarray(RNG.randn(P, ps, Hk, dh), dtype)
     vp = jnp.asarray(RNG.randn(P, ps, Hk, dh), dtype)
-    pt = jnp.asarray(RNG.randint(0, P, size=(B, MP)), jnp.int32)
-    clen = jnp.asarray(RNG.randint(1, MP * ps + 1, size=B))
+    pt = RNG.randint(0, P, size=(B, MP))
+    clen = RNG.randint(1, MP * ps + 1, size=B)
+    if lens == "edges":
+        bk = ps * pages_per_block(ps, Hk * dh, MP, kp.dtype.itemsize)
+        assert MP * ps > bk + 1 and MP % (bk // ps)
+        clen = np.asarray([1, bk, bk + 1, MP * ps])
+    elif lens == "trash":
+        pt[1] = P - 1
+        clen[0] = MP * ps + 7
+    pt, clen = jnp.asarray(pt, jnp.int32), jnp.asarray(clen)
     bias = validity_bias(B, MP * ps, clen)
     o, m, l = flash_decode_paged_op(q, kp, vp, pt, clen, interpret=True)
     orf, mrf, lrf = flash_decode_paged_ref(q, kp, vp, pt, bias)

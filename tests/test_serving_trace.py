@@ -168,6 +168,39 @@ def test_paged_chunk_counts_pages_of_busy_rows(session):
     assert any(v < h for v, h in counts)       # the cache holds pages too
 
 
+def test_paged_chunk_counts_kernel_blocks(session, monkeypatch):
+    """``kv_blocks_walked``: blocks the paged kernel walks at the chunk's
+    last step, from each row's attended length there (the lengths the
+    chunk returns: ``min(length, cap) + 1``); ``kv_blocks_table``: rows x
+    blocks of the whole table.  Blocks of two 8-token pages, so rows of
+    more than 16 tokens walk two of a table's two."""
+    from repro.kernels.flash_decode import paged
+    monkeypatch.setattr(paged, "MAX_BLOCK_TOKENS", 16)
+    rt = _runtime(session, PAGED, True, Ticker())
+    want = []
+
+    def sample(rid, toks):
+        if len(want) < rt.stats["chunks"]:
+            pool = next(iter(rt.pools.values()))
+            k = pool.pool["kv"]["k"]
+            ppb = paged.pages_per_block(8, k.shape[-2] * k.shape[-1],
+                                        pool.max_pages, k.dtype.itemsize)
+            assert ppb == 2 < pool.max_pages
+            lens = np.asarray(pool.lengths)
+            want.append((int(sum(-(-lens // (ppb * 8)))),
+                         pool.n_rows * -(-pool.max_pages // ppb)))
+    rt.on_progress = sample
+    for i in range(3):
+        rt.submit(BASE[:6 + 4 * i], 5 + 4 * i, seed=i)
+    rt.run()
+    got = [(sp.attrs["kv_blocks_walked"], sp.attrs["kv_blocks_table"])
+           for sp, _ in _chunk_spans(rt)]
+    assert got == want
+    assert all(w <= t for w, t in got)
+    assert any(w > rt.pools[next(iter(rt.pools))].n_rows for w, _ in got)
+    assert any(w < t for w, t in got)
+
+
 def test_admit_pass_spans_cover_the_admissions(session):
     rt = _runtime(session, PAGED, True, Ticker())
     reqs = [rt.submit(BASE[:5 + i], 9, seed=i) for i in range(5)]

@@ -56,9 +56,12 @@ def test_flash_decode_compiles(one_chip, S):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("page_size", [16, 32])
-def test_paged_flash_decode_compiles(one_chip, page_size):
-    B, H, Hk, dh, pages_per_row = 8, 16, 8, 128, 128
+# B = 8: a decode chunk's rows; B = 1: a suffix scan
+@pytest.mark.parametrize("page_size, B", [
+    pytest.param(ps, B, id=f"{ps}" + ("" if B == 8 else f"-B{B}"))
+    for ps in (16, 32) for B in (8, 1)])
+def test_paged_flash_decode_compiles(one_chip, page_size, B):
+    H, Hk, dh, pages_per_row = 16, 8, 128, 128
     n_pages = B * pages_per_row + 1
     pool = _shape(one_chip, (n_pages, page_size, Hk, dh))
     text = _compile(
