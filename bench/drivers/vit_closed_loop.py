@@ -108,8 +108,8 @@ def run(cell, args, t_start, devices) -> harness.RunRecord:
             session.dispatch({"images": images(0, b)})
         compiles = harness.CompileCounter()
         sizes = traffic.batch_sizes(p, args.seed, harness.rng)
-        prof = Profile(tempfile.mkdtemp(prefix="bench-trace-"), clock) \
-            if args.trace else None
+        prof = Profile(tempfile.mkdtemp(prefix="bench-trace-"), clock,
+                       w.get("profile")) if args.trace else None
         n_hist = len(session.history)
         t_open = clock()
         t_close = t_open + args.seconds
@@ -135,12 +135,16 @@ def run(cell, args, t_start, devices) -> harness.RunRecord:
           f"the window: {compiles.count} {sorted(set(compiles.names))}",
           flush=True)
     device = harness.device_info(devices)
-    trace = None
-    if prof is not None:
-        trace = prof.reduce()
-        shutil.rmtree(prof.log_dir, ignore_errors=True)
     records = [{"batch": b, "start": t0, "end": t1, "plan": h.exec_key}
                for (b, _, t0, t1, _), h in zip(done, history)]
+    plan_modes = {k: reference_mode(v) for k, v in session.plans.items()}
+    trace = None
+    if prof is not None:
+        trace = prof.reduce([("vit.batch", r["start"], r["end"])
+                             for r in records])
+        shutil.rmtree(prof.log_dir, ignore_errors=True)
+        _log_traced_window(records, plan_modes, m, t_open, prof,
+                           counts.peaks(device["kind"]))
     check = _check(session, weights, m, done, history, w["check"],
                    args.seed, images, max(p["batch_sizes"]))
     return harness.RunRecord(
@@ -151,10 +155,28 @@ def run(cell, args, t_start, devices) -> harness.RunRecord:
             images, max(p["batch_sizes"]), precision),
         data={"batches": records, "t_open": t_open, "t_close": t_close,
               "t_end": t_end, "model": m, "chips": len(devices),
-              "plans": {k: reference_mode(v) for k, v
-                        in session.plans.items()},
+              "plans": plan_modes,
               "trace_window": (prof.t_open, prof.t_close) if prof else None,
               "compiles": compiles.count})
+
+
+def _log_traced_window(records, plan_modes, m, t_open, prof, peak):
+    """Images per second before the traced window and inside it (what the
+    profiler costs the loop), and the share of one chip's peak that each
+    chip's model operations need inside it, from the batches wholly
+    inside: a split batch's operations spread over its partitions, a
+    local batch's all on every chip (its program runs replicated over the
+    mesh)."""
+    lo, hi = prof.t_open, prof.t_close
+    before = sum(r["batch"] for r in records if r["end"] <= lo)
+    inside = [r for r in records if lo <= r["start"] and r["end"] <= hi]
+    images = sum(r["batch"] for r in inside)
+    flops = sum(counts.vit_image_flops(m) * r["batch"]
+                / plan_modes[r["plan"]][0] for r in inside)
+    need = flops / ((hi - lo) * peak["bf16_flops_per_s"])
+    harness.log(f"trace: {before / (lo - t_open):.1f} images/s before the "
+                f"traced window, {images / (hi - lo):.1f} inside it; each "
+                f"chip's work there needs {100 * need:.3f}% of its peak")
 
 
 def _plan_counts(history):

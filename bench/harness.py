@@ -3,9 +3,11 @@
 Everything a cell needs is found by name: ``BENCHMARK.json`` (at the root
 of the checkout) names the cell's configuration, chips and metrics;
 ``workloads/<cell>.json`` holds the loop kind and every traffic
-parameter; ``configs/<config>.json`` the model and its deployment;
-``drivers/<kind>.py`` the loop; ``metrics/<metric>.py`` one reader per
-metric.  A new cell of an existing loop kind is one JSON file.
+parameter; ``configs/<config>.json`` the model and its deployment, and
+``models/<reference>.py`` (the file's ``reference``) its seeded weights
+and plain reference; ``drivers/<kind>.py`` the loop;
+``metrics/<metric>.py`` one reader per metric.  A new cell of an existing
+loop kind is one JSON file.
 """
 from __future__ import annotations
 
@@ -44,7 +46,9 @@ class Cell:
     name: str
     chips: int
     workload: Dict[str, Any]          # workloads/<name>.json
-    config: Dict[str, Any]            # configs/<config>.json
+    # configs/<config>.json, and "bench_dir": the directory it came from,
+    # where its "reference" module is found (models/<reference>.py)
+    config: Dict[str, Any]
     end_to_end: List[Dict[str, Any]]  # BENCHMARK.json metrics of this cell
     per_layer: List[Dict[str, Any]]
 
@@ -65,6 +69,7 @@ def load_cell(name: str, benchmark: Optional[Dict[str, Any]] = None,
         raise SystemExit(f"unknown workload {name!r}; known: {known}")
     workload = load_json(bench_dir / "workloads" / f"{name}.json")
     config = load_json(bench_dir / "configs" / f"{entry['config']}.json")
+    config["bench_dir"] = str(bench_dir)
     if workload.get("config", entry["config"]) != entry["config"]:
         raise SystemExit(f"{name}: workload file names config "
                          f"{workload['config']!r}, BENCHMARK.json "
@@ -261,6 +266,9 @@ def run_cell(name: str, args, t_start: float, platform: str = "tpu",
     if args.trace:
         rec.device["busy_s"] = rec.trace.busy_s()
         rec.device["window_s"] = rec.trace.window_s
+        log("trace: busy share of each device "
+            + ", ".join(f"{100 * b:.3f}%" for b in rec.trace.busy_shares())
+            + f" of {rec.trace.window_s:.3f} s")
         breakdown = {"device_ops": rec.trace.top_ops(),
                      "idle_gaps": rec.trace.idle_gaps()}
     metrics = read_metrics(rec, rec.cell.per_layer if args.trace

@@ -9,42 +9,32 @@ chunk, when a streaming client receives tokens.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
+import pathlib
 import time
 from typing import Any, Dict, Iterable, List, Optional
 
 import numpy as np
 
 from bench import harness
-from bench.models import lm_dense
 from bench.traffic import LMRequest
 
 clock = time.perf_counter
 
 
-def program_config(config: Dict[str, Any]):
-    """The program's ``ModelConfig`` at the sizes the file states."""
-    import dataclasses as dc
-    from repro.configs import get_config
-    m = config["model"]
-    cfg = get_config(config["program_arch"])
-    cfg = dc.replace(cfg, n_layers=m["num_hidden_layers"],
-                     d_model=m["hidden_size"],
-                     n_heads=m["num_attention_heads"],
-                     n_kv_heads=m["num_key_value_heads"],
-                     head_dim=m["head_dim"], d_ff=m["intermediate_size"],
-                     vocab_size=m["vocab_size"], rope_theta=m["rope_theta"])
-    expect = {"family": "dense", "norm_type": "rmsnorm", "act": "silu",
-              "tie_embeddings": m["tie_word_embeddings"], "qkv_bias": False,
-              "dtype": m["torch_dtype"], "window": None,
-              "attn_softcap": None, "final_softcap": None,
-              "embed_scale": False, "post_norms": False}
-    wrong = {k: getattr(cfg, k) for k, v in expect.items()
-             if getattr(cfg, k) != v}
-    if wrong:
-        raise SystemExit(f"{config['name']}: the program's config differs "
-                         f"from the reference's semantics: {wrong}")
-    return cfg
+def reference(config: Dict[str, Any]):
+    """The configuration's reference module, ``models/<reference>.py`` of
+    the benchmark the cell was loaded from.  It provides
+    ``program_config(config)``, ``make_weights(m, key_int)``,
+    ``program_params(weights)`` and ``served_gaps(...)``."""
+    return _reference(config["reference"], config["bench_dir"])
+
+
+@functools.lru_cache(maxsize=None)
+def _reference(name: str, bench_dir: str):
+    # one module per file, so its jitted reference is traced once a process
+    return harness.load_module("models", name, pathlib.Path(bench_dir))
 
 
 def same_tree(params, cfg) -> None:
@@ -95,10 +85,11 @@ class Server:
         from repro.serving import ServingRuntime
         self.m = config["model"]
         dep = config["deployment"]
-        self.weights = lm_dense.make_weights(self.m, harness.key_int(seed, 0))
+        self.ref = reference(config)
+        self.weights = self.ref.make_weights(self.m, harness.key_int(seed, 0))
         jax.block_until_ready(self.weights)
-        cfg = program_config(config)
-        params = lm_dense.program_params(self.weights)
+        cfg = self.ref.program_config(config)
+        params = self.ref.program_params(self.weights)
         same_tree(params, cfg)
         session = InferenceSession(cfg, params,
                                    plans=[ExecutionPlan.local()],
@@ -251,8 +242,8 @@ def check_served(server: Server, recs: List[Rec], check: Dict[str, Any],
     sample = [top] + [rest[i] for i in pick[:check["sample"] - 1]]
     widest = 0.0
     for r in sample:
-        g = lm_dense.served_gaps(server.weights, server.m, r.prompt,
-                                 r.tokens, *shape, precision)
+        g = server.ref.served_gaps(server.weights, server.m, r.prompt,
+                                   r.tokens, *shape, precision)
         widest = max(widest, float(np.max(g)))
     harness.log(f"check: {len(sample)} requests, "
                 f"{sum(r.n_new for r in sample)} served tokens")

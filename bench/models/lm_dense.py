@@ -1,10 +1,12 @@
 """Dense GQA decoder (internlm2-1.8b): seeded weights and the plain
 float32 reference.
 
-This module imports nothing of the program.  The benchmark draws the
+The reference imports nothing of the program.  The benchmark draws the
 weights here, on the device, in one jitted call; the serving loop hands
 the same arrays to the program in the program's layout
-(``program_params``), and the reference reads them here in its own.
+(``program_params``), at the sizes ``program_config`` maps onto the
+program's own configuration (the one place that reads the program's
+table), and the reference reads them here in its own.
 
 Reference: pre-norm decoder blocks, RMSNorm with gain ``1 + g``, rotary
 positions by half rotation (theta from the configuration), grouped-query
@@ -21,7 +23,7 @@ from __future__ import annotations
 
 import functools
 import json
-from typing import Dict
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +36,31 @@ def dims(m: Dict):
     return (m["num_hidden_layers"], m["hidden_size"],
             m["num_attention_heads"], m["num_key_value_heads"],
             m["head_dim"], m["intermediate_size"], m["vocab_size"])
+
+
+def program_config(config: Dict[str, Any]):
+    """The program's ``ModelConfig`` at the sizes the file states."""
+    import dataclasses as dc
+    from repro.configs import get_config
+    m = config["model"]
+    cfg = get_config(config["program_arch"])
+    cfg = dc.replace(cfg, n_layers=m["num_hidden_layers"],
+                     d_model=m["hidden_size"],
+                     n_heads=m["num_attention_heads"],
+                     n_kv_heads=m["num_key_value_heads"],
+                     head_dim=m["head_dim"], d_ff=m["intermediate_size"],
+                     vocab_size=m["vocab_size"], rope_theta=m["rope_theta"])
+    expect = {"family": "dense", "norm_type": "rmsnorm", "act": "silu",
+              "tie_embeddings": m["tie_word_embeddings"], "qkv_bias": False,
+              "dtype": m["torch_dtype"], "window": None,
+              "attn_softcap": None, "final_softcap": None,
+              "embed_scale": False, "post_norms": False}
+    wrong = {k: getattr(cfg, k) for k, v in expect.items()
+             if getattr(cfg, k) != v}
+    if wrong:
+        raise SystemExit(f"{config['name']}: the program's config differs "
+                         f"from the reference's semantics: {wrong}")
+    return cfg
 
 
 def make_weights(m: Dict, key_int: int):

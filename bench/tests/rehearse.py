@@ -43,9 +43,9 @@ TINY_CHECK = {"serve_open_loop": dict(limit=0.05),
 
 
 def tiny_tree(dest: pathlib.Path) -> pathlib.Path:
-    """A copy of BENCHMARK.json (with the pending cells added) and the
-    benchmark's files under ``dest``, every configuration and workload cut
-    to CPU size; returns the copy's benchmark directory."""
+    """A copy of BENCHMARK.json and the benchmark's files under ``dest``,
+    every configuration and workload cut to CPU size; returns the copy's
+    benchmark directory."""
     bench = dest / "bench"
     shutil.copytree(ROOT / "bench", bench,
                     ignore=shutil.ignore_patterns(".jax_cache", "__pycache__",
@@ -61,76 +61,14 @@ def tiny_tree(dest: pathlib.Path) -> pathlib.Path:
         w["traffic"].update(TINY_TRAFFIC.get(w["driver"], {}))
         w["check"].update(TINY_CHECK.get(w["driver"], {}))
         w["trace_s"] = 1
+        # the CPU backend's operations are host events: trace them
+        w.pop("profile", None)
         path.write_text(json.dumps(w))
-    add_pending(bench)
     return bench
 
 
-# cells whose loop, workload and metric files are in the benchmark but
-# which are not in BENCHMARK.json (PERF.md says why): their entries as a
-# later benchmark PR would add them, so the tests still run them
 SHARED_PREFIX = "internlm2-1.8b.shared-prefix"
 VIT = "vit-base-16.adaptive-4chip"
-
-
-def _layer(name, unit, better, moves, cell):
-    return {"name": name, "unit": unit, "better": better,
-            "source": "device_trace", "layer": "rehearsal", "moves": moves,
-            "workloads": [cell]}
-
-
-PENDING = {
-    SHARED_PREFIX: {
-        "config": {"name": "internlm2-1.8b"},
-        "end_to_end": [("ttft_p90_ms", "ms", "lower"),
-                       ("tpot_p90_ms", "ms", "lower")],
-        "per_layer": [("queue_wait_p50_ms.prefix", "ms", "lower"),
-                      ("prefix_hit_share.prefix", "%", "higher"),
-                      ("prefill_device_ms.prefix", "ms", "lower"),
-                      ("mfu.prefix", "%", "higher")],
-        "chips": 1},
-    VIT: {
-        "config": {"name": "vit-base-16", "source": "arXiv:2010.11929",
-                   "file": "bench/configs/vit-base-16.json", "reduced": [],
-                   "why": "rehearsal"},
-        "end_to_end": [("images_s", "images/s", "higher")],
-        "per_layer": [("split_share.vit", "%", "higher"),
-                      ("collective_share.vit", "%", "lower"),
-                      ("segment_means_roofline.vit", "%", "higher"),
-                      ("idle_share.vit", "%", "lower"),
-                      ("mfu.vit", "%", "higher")],
-        "chips": 4},
-}
-
-
-def add_pending(bench: pathlib.Path) -> None:
-    """Add the pending cells to a throwaway tree's BENCHMARK.json."""
-    path = bench.parent / "BENCHMARK.json"
-    bm = json.loads(path.read_text())
-    listed = {w["name"] for w in bm["workloads"]}
-    for cell, e in PENDING.items():
-        if cell in listed:
-            continue
-        config = e["config"]["name"]
-        if all(c["name"] != config for c in bm["configs"]):
-            bm["configs"].append(e["config"])
-        bm["workloads"].append({"name": cell, "config": config,
-                                "traffic": cell.split(".", 1)[1],
-                                "chips": e["chips"], "why": "rehearsal"})
-        moves = e["end_to_end"][0][0]
-        for name, unit, better in e["end_to_end"]:
-            old = next((m for m in bm["end_to_end"] if m["name"] == name),
-                       None)
-            if old is None:
-                bm["end_to_end"].append(
-                    {"name": name, "unit": unit, "better": better,
-                     "bound": 0.25, "source": "host_clock",
-                     "workloads": [cell]})
-            elif "workloads" in old:
-                old["workloads"].append(cell)
-        bm["per_layer"] += [_layer(n, u, b, moves, cell)
-                            for n, u, b in e["per_layer"]]
-    path.write_text(json.dumps(bm))
 
 
 def cpu_peaks() -> None:

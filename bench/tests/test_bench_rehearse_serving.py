@@ -34,7 +34,8 @@ def test_traced_run_reports_per_layer_metrics(tree):
     assert out["correct"] is True
     assert set(out["metrics"]) == {
         "queue_wait_p50_ms.prefix", "prefix_hit_share.prefix",
-        "prefill_device_ms.prefix", "mfu.prefix"}
+        "prefill_device_ms.prefix", "mfu.prefix",
+        "first_token_wait_p50_ms.prefix", "rows_busy_share.prefix"}
     assert out["metrics"]["prefix_hit_share.prefix"]["value"] == 100.0
     assert 0 < out["device"]["busy_s"] <= out["device"]["window_s"]
     assert out["breakdown"]["device_ops"] and out["breakdown"]["idle_gaps"]
@@ -64,6 +65,67 @@ def test_a_new_cell_is_one_workload_file(tree):
         (rehearse.ROOT / "BENCHMARK.json").read_text())["workloads"]
 
 
+STUB_REFERENCE = '''\
+"""A throwaway reference: the dense decoder's, recording its calls."""
+import pathlib
+
+from bench.models import lm_dense
+from bench.models.lm_dense import program_config, program_params
+
+CALLS = pathlib.Path(__file__).with_suffix(".calls")
+
+
+def _called(name):
+    with open(CALLS, "a") as f:
+        f.write(name + "\\n")
+
+
+def make_weights(m, key_int):
+    _called("make_weights")
+    return lm_dense.make_weights(m, key_int)
+
+
+def served_gaps(*args):
+    _called("served_gaps")
+    return lm_dense.served_gaps(*args)
+'''
+
+
+def test_a_new_configuration_brings_its_reference_by_name(tree):
+    """A configuration file names its reference module, and the serving
+    loop takes weights, program sizes and the check from that module:
+    one configuration file, one module, one workload file and their
+    entries in BENCHMARK.json; no code changes."""
+    cell = "stub-lm.backlog"
+    config = json.loads((tree / "configs" / "internlm2-1.8b.json").read_text())
+    config.update(name="stub-lm", reference="stub_lm")
+    (tree / "configs" / "stub-lm.json").write_text(json.dumps(config))
+    (tree / "models" / "stub_lm.py").write_text(STUB_REFERENCE)
+    w = json.loads((tree / "workloads" /
+                    "internlm2-1.8b.decode-backlog.json").read_text())
+    w["config"] = "stub-lm"
+    (tree / "workloads" / f"{cell}.json").write_text(json.dumps(w))
+    bm_path = tree.parent / "BENCHMARK.json"
+    bm = json.loads(bm_path.read_text())
+    bm["configs"].append({"name": "stub-lm", "source": "rehearsal",
+                          "file": "bench/configs/stub-lm.json",
+                          "reduced": [], "why": "rehearsal"})
+    bm["workloads"].append({"name": cell, "config": "stub-lm",
+                            "traffic": "backlog", "chips": 1,
+                            "why": "rehearsal"})
+    for m in bm["end_to_end"]:
+        if "internlm2-1.8b.decode-backlog" in m.get("workloads", []):
+            m["workloads"].append(cell)
+    bm_path.write_text(json.dumps(bm))
+    out = rehearse.run(cell, 0, tree)
+    assert out["correct"] is True and out["attempted"] > 0
+    assert set(out["metrics"]) == {"output_tok_s", "tpot_p90_ms", "setup_s"}
+    calls = (tree / "models" / "stub_lm.calls").read_text().split()
+    assert calls[0] == "make_weights" and calls.count("make_weights") == 1
+    assert 1 <= calls.count("served_gaps") <= w["check"]["sample"]
+    assert not (rehearse.ROOT / "bench" / "models" / "stub_lm.py").exists()
+
+
 def test_run_refuses_without_a_tpu():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     p = subprocess.run(
@@ -88,8 +150,8 @@ def test_backlog_traced_run(tree):
     out = rehearse.run("internlm2-1.8b.decode-backlog", 1, tree)
     assert out["correct"] is True
     m = out["metrics"]
-    assert {"pages_used_share.backlog", "decode_step_ms.backlog",
+    assert {"pages_valid_share.backlog", "decode_step_ms.backlog",
             "idle_share.backlog", "mfu.backlog"} <= set(m)
-    assert 0 < m["pages_used_share.backlog"]["value"] <= 100
+    assert 0 < m["pages_valid_share.backlog"]["value"] <= 100
     # the CPU runs the paged decode reference, not the kernel: no reading
     assert "paged_decode_roofline.backlog" not in m

@@ -7,7 +7,7 @@ import sys
 
 from bench.tests import rehearse
 
-CELL = "vit-base-16.adaptive-4chip"
+CELL = rehearse.VIT
 
 
 def rehearse_4(tmp_path, trace):
@@ -31,9 +31,14 @@ def test_traced_run(tmp_path):
     out = rehearse_4(tmp_path, 1)
     assert out["correct"] is True
     m = out["metrics"]
+    listed = {p["name"] for p in json.loads(
+        (rehearse.ROOT / "BENCHMARK.json").read_text())["per_layer"]
+        if CELL in p.get("workloads", [])}
+    assert {n for n in listed if n.endswith(".vit")} == listed
+    # every one of the cell's metrics, but the CPU runs the segment-means
+    # reference and not the kernel: that one has no reading
+    assert set(m) == listed - {"segment_means_roofline.vit"}
     # the policy splits batches of 8 and more: (8 + 16 + 32) / 63 of images
     assert abs(m["split_share.vit"]["value"] - 100 * 56 / 63) < 10
     assert m["collective_share.vit"]["value"] > 0
-    assert {"idle_share.vit", "mfu.vit"} <= set(m)
-    # the CPU runs the segment-means reference, not the kernel: no reading
-    assert "segment_means_roofline.vit" not in m
+    assert out["device"]["count"] == 4

@@ -19,12 +19,15 @@ import dataclasses
 import glob
 import os
 import re
+import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 DEVICE_PLANE = re.compile(r"^/device:[A-Z]+:(\d+)$")
+# a TPU runs an all-gather in part as ``async-collective-start``/``-done``
 COLLECTIVE = re.compile(
     r"all[-_]?gather|all[-_]?reduce|reduce[-_]?scatter|"
-    r"collective[-_]?permute|all[-_]?to[-_]?all", re.IGNORECASE)
+    r"collective[-_]?permute|all[-_]?to[-_]?all|async[-_]?collective",
+    re.IGNORECASE)
 _SUFFIX = re.compile(r"(\.\d+)+$|\(\d+\)$")
 CONTAINERS = ("while", "conditional", "call")
 # a TPU trace names each operation by its HLO text: "%fusion.12 = f32[..."
@@ -95,6 +98,7 @@ class Trace:
     lo: int                      # traced window [lo, hi) in ns
     hi: int
     offset: int = 0              # trace ns = host seconds * 1e9 + offset
+    epoch_ns: Optional[int] = None   # wall clock (ns) at the trace's 0
 
     def at(self, host_s: float) -> int:
         """A host-clock time (``Profile``'s clock) on the trace's clock."""
@@ -111,6 +115,11 @@ class Trace:
 
     def idle_share(self) -> float:
         return 1.0 - self.busy_s() / self.window_s
+
+    def busy_shares(self) -> List[float]:
+        """Each device's busy share of the traced window."""
+        return [d.busy_ns(self.lo, self.hi) / (self.hi - self.lo)
+                for d in self.devices]
 
     def collective_s(self, device: int = 0) -> float:
         return self.devices[device].op_time_s(
@@ -180,20 +189,28 @@ def load(path: str, window: Optional[Tuple[int, int]] = None) -> Trace:
     bounds the traced window; by default the ``OPEN``/``CLOSE``
     annotations do, or else every event."""
     from jax.profiler import ProfileData
-    pd = ProfileData.from_file(path)
+    return from_planes(ProfileData.from_file(path).planes, window)
+
+
+def from_planes(planes, window: Optional[Tuple[int, int]] = None) -> Trace:
+    """The trace of the profiler's planes (each with a ``name`` and
+    ``lines``, a line with a ``name`` and ``events``).  Every device plane
+    counts, one that holds no operation as idle all through."""
     devices: List[Device] = []
     host_lines = []
-    for plane in pd.planes:
+    epoch = None
+    for plane in planes:
         m = DEVICE_PLANE.match(plane.name)
         if m:
             lines = {ln.name: ln for ln in plane.lines}
             ops = _events(lines["XLA Ops"]) if "XLA Ops" in lines else []
             mods = (_events(lines["XLA Modules"]) if "XLA Modules" in lines
                     else [])
-            if ops or mods:
-                devices.append(Device(int(m.group(1)), ops, mods))
+            devices.append(Device(int(m.group(1)), ops, mods))
         elif plane.name.startswith("/host:"):
             host_lines.extend(plane.lines)
+        elif plane.name == "Task Environment":
+            epoch = dict(plane.stats).get("profile_start_time")
     host: List[Event] = []
     cpu_ops: List[Event] = []
     for ln in host_lines:
@@ -218,36 +235,58 @@ def load(path: str, window: Optional[Tuple[int, int]] = None) -> Trace:
     if window is None:
         every = [e for d in devices for e in d.ops + d.modules] + host
         window = (min(e.start for e in every), max(e.end for e in every))
-    return Trace(devices, host, int(window[0]), int(window[1]))
+    return Trace(devices, host, int(window[0]), int(window[1]),
+                 epoch_ns=None if epoch is None else int(epoch))
 
 
 class Profile:
     """The profiler over one window of a run: ``start``/``stop`` write the
     anchor annotations, ``reduce`` reads the trace back.  ``spans`` maps
     host-clock spans (seconds, ``time.perf_counter``) onto the trace's
-    clock, so the program's own spans can name idle gaps too."""
+    clock, so the program's own spans can name idle gaps too.
 
-    def __init__(self, log_dir: str, clock):
-        self.log_dir, self.clock = log_dir, clock
+    ``options`` (a workload's ``"profile"``) sets the profiler's
+    ``host_tracer_level`` and ``python_tracer_level``.  A host tracer that
+    records every small host event can slow a host-bound loop several
+    times over while it runs, and the traced window then stands for
+    nothing else.  With the host tracer off (level 0) the anchors are not
+    recorded, and the wall clock, read beside the host clock, places the
+    window on the trace's clock (whose 0 is the profile's start time)."""
+
+    def __init__(self, log_dir: str, clock,
+                 options: Optional[Dict[str, int]] = None):
+        self.log_dir, self.clock, self.options = log_dir, clock, options
         self.t_open = self.t_close = None
 
     def start(self) -> None:
         import jax
-        jax.profiler.start_trace(self.log_dir)
+        opts = None
+        if self.options:
+            opts = jax.profiler.ProfileOptions()
+            for k, v in self.options.items():
+                setattr(opts, k, v)
+        jax.profiler.start_trace(self.log_dir, profiler_options=opts)
         with jax.profiler.TraceAnnotation(OPEN):
             self.t_open = self.clock()
+            self.wall_open = time.time_ns()
 
     def stop(self) -> None:
         import jax
         with jax.profiler.TraceAnnotation(CLOSE):
             self.t_close = self.clock()
+            self.wall_close = time.time_ns()
         jax.profiler.stop_trace()
 
     def reduce(self, spans: Sequence[Tuple[str, float, float]] = ()
                ) -> Trace:
         trace = load(find_xplane(self.log_dir))
-        anchor = next(e for e in trace.host if e.name == OPEN)
-        trace.offset = anchor.start - int(1e9 * self.t_open)
+        anchor = next((e for e in trace.host if e.name == OPEN), None)
+        if anchor is not None:
+            opened = anchor.start
+        else:                        # host tracer off: the wall clock
+            opened = self.wall_open - trace.epoch_ns
+            trace.lo, trace.hi = opened, self.wall_close - trace.epoch_ns
+        trace.offset = opened - int(1e9 * self.t_open)
         for name, s, e in spans:
             trace.host.append(Event(name, trace.at(s), trace.at(e), {}))
         return trace
