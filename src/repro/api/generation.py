@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core.exchange import ExchangeConfig, ExchangeMode
 from repro.models import transformer as tfm
+from repro.models.mla import pad_lanes
 
 _STATS = {"dispatches": 0, "builds": 0}
 
@@ -60,16 +61,13 @@ def resolve_prefill_mode(cfg: ModelConfig, xcfg: ExchangeConfig,
     """Pick the prefill implementation: "single_pass" or "scan".
 
     "auto" chooses single-pass when the family supports it AND the
-    full-sequence math is exact w.r.t. the decode path:
-
-    * PRISM plans prefill through compressed segment means — the paper's
-      distributed-prefill semantics, but not token-for-token equal to the
-      legacy decode loop — so "auto" keeps them scanned; pass
-      ``prefill_mode="single_pass"`` explicitly for the compressed prefill.
-    * MoE full-sequence routing uses a capacity ∝ seq-len and can DROP
-      token-expert assignments that per-token decode (capacity 1/step)
-      never drops, so "auto" keeps MoE scanned too; forcing single-pass
-      gives the forward/training routing semantics.
+    full-sequence math is exact w.r.t. the decode path.  PRISM plans
+    prefill through compressed segment means — the paper's
+    distributed-prefill semantics, but not token-for-token equal to the
+    legacy decode loop — so "auto" keeps them scanned; pass
+    ``prefill_mode="single_pass"`` explicitly for the compressed prefill.
+    (MoE layers are dropless: a prompt routes in one pass exactly as its
+    tokens do one at a time.)
     """
     if mode == "scan":
         return "scan"
@@ -82,9 +80,8 @@ def resolve_prefill_mode(cfg: ModelConfig, xcfg: ExchangeConfig,
     if mode != "auto":
         raise ValueError(f"prefill_mode {mode!r}: one of "
                          f"'auto' | 'single_pass' | 'scan'")
-    exact = ((xcfg.mode in (ExchangeMode.LOCAL, ExchangeMode.VOLTAGE)
-              or xcfg.seq_axis is None or xcfg.seq_shards == 1)
-             and cfg.moe is None)
+    exact = (xcfg.mode in (ExchangeMode.LOCAL, ExchangeMode.VOLTAGE)
+             or xcfg.seq_axis is None or xcfg.seq_shards == 1)
     return "single_pass" if (supported and exact) else "scan"
 
 
@@ -396,9 +393,11 @@ def build_paged_admit_fn(cfg: ModelConfig) -> Callable:
               tok0, length0, key0, temp0):
         def scatter(p, r):
             # p: [L, P, ps, Hk, dh] pool leaf; r: [L, 1, P0*ps, Hk, dh]
+            # (a latent pool's k_pe is wider than the request's: padded)
             ps = p.shape[2]
             P0 = r.shape[2] // ps
-            r = r.astype(p.dtype).reshape(r.shape[0], P0, ps, *r.shape[3:])
+            r = pad_lanes(r, p.shape[-1]).astype(p.dtype)
+            r = r.reshape(r.shape[0], P0, ps, *r.shape[3:])
             return p.at[:, page_ids].set(r)
 
         pool = jax.tree_util.tree_map(scatter, pool, req_cache)
@@ -475,7 +474,7 @@ def build_paged_suffix_fn(cfg: ModelConfig, xcfg: ExchangeConfig, *,
         def step(carry, xs):
             pool, _ = carry
             t, i = xs
-            logits, pool = tfm.decode_step_paged(
+            logits, pool, _ = tfm.decode_step_paged(
                 params, {"tokens": t[:, None]}, pool, row_table,
                 start_len + i, cfg, xcfg)
             return (pool, logits), None
@@ -509,7 +508,10 @@ def build_paged_decode_chunk_fn(cfg: ModelConfig, xcfg: ExchangeConfig, *,
     """One jitted continuous-batching decode chunk over the paged pool.
 
     ``fn(params, pool, page_table [S, MP], caps [S], tok [S], lengths [S],
-    keys [S], temps [S]) → (tokens [S, n_steps], pool, lengths, keys)``.
+    keys [S], temps [S]) → (tokens [S, n_steps], pool, lengths, keys,
+    expert tokens)``; the last is [n_steps, moe_layers, n_held] int32, the
+    tokens each held expert computed at each step (None without experts):
+    it stays on the device unless a caller reads it.
     All rows advance together through ``decode_step_paged`` (the pool is
     shared state); per-row sampling applies exactly the per-slot math of
     ``build_decode_chunk_fn``.  ``caps`` [S] is each row's last writable
@@ -530,15 +532,15 @@ def build_paged_decode_chunk_fn(cfg: ModelConfig, xcfg: ExchangeConfig, *,
         def step(carry, _):
             tok, pool, lengths, keys = carry
             pos = jnp.minimum(lengths, caps)
-            logits, pool = tfm.decode_step_paged(
+            logits, pool, counts = tfm.decode_step_paged(
                 params, {"tokens": tok[:, None]}, pool, page_table, pos,
                 cfg, xcfg)
             nxt, keys = jax.vmap(samp)(logits[:, 0], keys, temps)
-            return (nxt, pool, pos + 1, keys), nxt
+            return (nxt, pool, pos + 1, keys), (nxt, counts)
 
-        (tok, pool, lengths, keys), toks = jax.lax.scan(
+        (tok, pool, lengths, keys), (toks, counts) = jax.lax.scan(
             step, (tok, pool, lengths, keys), None, length=n_steps)
-        return toks.T, pool, lengths, keys
+        return toks.T, pool, lengths, keys, counts
 
     # donated pool: the chunk runs every scheduler step, and an undonated
     # pool costs a full pool copy at the jit boundary each time

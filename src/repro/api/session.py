@@ -676,11 +676,15 @@ class InferenceSession:
 
     def paged_decode_chunk(self, pool, page_table, caps, tok, lengths, keys,
                            temps, *, n_steps: int,
-                           plan: Optional[ExecutionPlan] = None):
+                           plan: Optional[ExecutionPlan] = None,
+                           with_counts: bool = False):
         """``n_steps`` continuous-batching decode steps over every page-
         table row → ``(tokens [S, n_steps], pool, lengths, keys)``;
         compiled once per (plan, rows, max_pages, n_steps) and reused
-        across admissions — page tables/caps/lengths are traced inputs."""
+        across admissions — page tables/caps/lengths are traced inputs.
+        ``with_counts`` appends the tokens each held expert computed,
+        ``[n_steps, moe_layers, n_held]`` int32 on the device (None for a
+        model without experts)."""
         from repro.api import generation as gen
         plan = self._plan_or_default(plan)
         fn = self._serve_exec(
@@ -688,8 +692,11 @@ class InferenceSession:
                    int(page_table.shape[1])),
             lambda: gen.build_paged_decode_chunk_fn(
                 self.cfg, plan.to_exchange_config(), n_steps=n_steps))
-        return fn(self.params, pool, page_table, caps, tok, lengths, keys,
-                  temps)
+        toks, pool, lengths, keys, counts = fn(
+            self.params, pool, page_table, caps, tok, lengths, keys, temps)
+        if with_counts:
+            return toks, pool, lengths, keys, counts
+        return toks, pool, lengths, keys
 
     # -- explanation (the paper's reported artifacts) ------------------------
 

@@ -5,7 +5,7 @@ import importlib
 
 from repro.configs.base import (ALL_SHAPES, LONG_CONTEXT_ARCHS, SHAPES_BY_NAME,
                                 MLACfg, ModelConfig, MoECfg, ShapeSpec, SSMCfg,
-                                shapes_for)
+                                YaRNCfg, shapes_for)
 
 _MODULES = {
     "qwen1.5-32b": "qwen1_5_32b",
@@ -14,6 +14,7 @@ _MODULES = {
     "gemma2-27b": "gemma2_27b",
     "deepseek-v2-236b": "deepseek_v2_236b",
     "deepseek-moe-16b": "deepseek_moe_16b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
     "whisper-large-v3": "whisper_large_v3",
     "llama-3.2-vision-11b": "llama3_2_vision_11b",
     "hymba-1.5b": "hymba_1_5b",
@@ -32,5 +33,5 @@ def get_config(name: str) -> ModelConfig:
 
 
 __all__ = ["get_config", "ASSIGNED_ARCHS", "ALL_SHAPES", "SHAPES_BY_NAME",
-           "ModelConfig", "MoECfg", "MLACfg", "SSMCfg", "ShapeSpec",
+           "ModelConfig", "MoECfg", "MLACfg", "SSMCfg", "ShapeSpec", "YaRNCfg",
            "shapes_for", "LONG_CONTEXT_ARCHS"]

@@ -14,23 +14,53 @@ import jax.numpy as jnp
 
 @dataclasses.dataclass(frozen=True)
 class MoECfg:
-    n_experts: int                # routed experts
+    n_experts: int                # routed experts: the router's outputs
     top_k: int
     n_shared: int = 0             # always-on shared experts
     d_ff_expert: int = 0          # expert hidden dim
     first_dense_layers: int = 1   # leading layers that use a dense MLP
     d_ff_dense: int = 0           # hidden dim of those dense MLPs
-    capacity_factor: float = 1.25
+    norm_topk_prob: bool = True   # renormalise the top-k probabilities
+    routed_scaling_factor: float = 1.0
+    # [first, end) of the router's experts whose weights this device holds
+    # (expert parallelism: the device computes their part of the result);
+    # None holds every expert
+    held: Optional[Tuple[int, int]] = None
     router_aux_weight: float = 0.001
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        return self.held or (0, self.n_experts)
+
+    @property
+    def n_held(self) -> int:
+        lo, hi = self.held_range
+        return hi - lo
 
 
 @dataclasses.dataclass(frozen=True)
 class MLACfg:
     kv_lora_rank: int = 512
-    q_lora_rank: int = 1536
+    q_lora_rank: Optional[int] = 1536   # None: queries projected directly
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class YaRNCfg:
+    """YaRN rope scaling (arXiv:2309.00071) in DeepSeek-V2's form: the
+    rotary frequencies blend the original and the ``factor``-interpolated
+    ones by a linear ramp between the correction dims of ``beta_fast`` and
+    ``beta_slow``; cos/sin are scaled by mscale(mscale) /
+    mscale(mscale_all_dim), and the softmax scale by
+    mscale(mscale_all_dim)^2."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,6 +93,7 @@ class ModelConfig:
     window: Optional[int] = None            # sliding window (local layers)
     local_global: bool = False              # gemma2 alternation local,global,...
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YaRNCfg] = None  # latent (MLA) attention only
     query_scale: Optional[float] = None     # override 1/sqrt(head_dim)
     # block structure
     norm_type: str = "rmsnorm"
@@ -109,10 +140,12 @@ class ModelConfig:
         if self.moe:
             base["moe"] = dataclasses.replace(
                 self.moe, n_experts=4, top_k=2, n_shared=min(self.moe.n_shared, 1),
-                d_ff_expert=32, d_ff_dense=128, first_dense_layers=1)
+                d_ff_expert=32, d_ff_dense=128, first_dense_layers=1,
+                held=None)
         if self.mla:
-            base["mla"] = MLACfg(kv_lora_rank=32, q_lora_rank=48,
-                                 qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16)
+            base["mla"] = MLACfg(
+                kv_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16,
+                q_lora_rank=48 if self.mla.q_lora_rank else None)
         if self.ssm:
             base["ssm"] = dataclasses.replace(self.ssm, state_size=8, chunk=8,
                                               slstm_every=2, mlstm_heads=2)

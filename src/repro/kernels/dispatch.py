@@ -223,6 +223,56 @@ def decode_attention_paged(q: jnp.ndarray,           # [B, 1, H, dh]
         q, k, v, kv_mask=valid, logit_softcap=logit_softcap, scale=scale)
 
 
+def decode_attention_latent_paged(q_lat: jnp.ndarray,     # [B, 1, H, r]
+                                  q_pe: jnp.ndarray,      # [B, 1, H, dr]
+                                  c_pool: jnp.ndarray,    # [P, ps, r]
+                                  pe_pool: jnp.ndarray,   # [P, ps, dp >= dr]
+                                  page_table: jnp.ndarray,  # [B, max_pages]
+                                  cache_len,              # [B] valid prefix
+                                  *, scale: float) -> jnp.ndarray:
+    """Absorbed latent (MLA) attention of one token against a *paged*
+    latent pool → the latent output [B, 1, H, r] (``W_uv`` comes after).
+
+    Pallas path: the paged latent kernel copies each row's valid
+    ``c_kv``/``k_pe`` pages through the scalar-prefetched table.  Compiled,
+    it needs the latent rank to be a lane multiple (128); other ranks run
+    the reference (counted ``decode_attention_latent_paged/latent_dim``).
+    Reference: ``jnp.take`` gather and a float32 masked softmax.
+    """
+    from repro.kernels.flash_decode import latent
+    B = q_lat.shape[0]
+    lengths = jnp.broadcast_to(jnp.reshape(
+        jnp.asarray(cache_len, jnp.int32), (-1,)), (B,))
+    if _use_pallas():
+        if _interpret() or c_pool.shape[-1] % 128 == 0:
+            o, m, l = latent.flash_decode_latent_pallas(
+                q_lat[:, 0], q_pe[:, 0], c_pool, pe_pool, page_table,
+                lengths, scale=scale, interpret=_interpret())
+            out = o / jnp.maximum(l, 1e-38)[..., None]            # [B, H, r]
+            return out[:, None].astype(q_lat.dtype)
+        _fallback("decode_attention_latent_paged", "latent_dim")
+    out = latent.flash_decode_latent_ref(q_lat[:, 0], q_pe[:, 0], c_pool,
+                                         pe_pool, page_table, lengths,
+                                         scale=scale)
+    return out[:, None].astype(q_lat.dtype)
+
+
+def paged_pages_per_block(pool, max_pages: int) -> int:
+    """Pages per grid step of the paged decode kernel that reads ``pool``
+    (a paged pool pytree: K/V leaves ``k``/``v``, or latent leaves
+    ``c_kv``/``k_pe``), from the geometry of its leaves."""
+    from repro.kernels.flash_decode.paged import pages_per_block
+    leaves = {path[-1].key: leaf for path, leaf in
+              jax.tree_util.tree_leaves_with_path(pool)}
+    if "c_kv" in leaves:            # the latent kernel: c_kv + padded k_pe
+        c, pe = leaves["c_kv"], leaves["k_pe"]
+        return pages_per_block(c.shape[-2], c.shape[-1] + pe.shape[-1],
+                               max_pages, c.dtype.itemsize)
+    k = leaves["k"]
+    return pages_per_block(k.shape[-3], k.shape[-2] * k.shape[-1],
+                           max_pages, k.dtype.itemsize)
+
+
 # ---------------------------------------------------------------------------
 # PRISM prefill attention (scaling-aware softmax over local ‖ remote means)
 # ---------------------------------------------------------------------------

@@ -14,10 +14,12 @@ Conventions
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.exchange import (ExchangeConfig, ExchangeMode,
                                  decode_attention_sharded, exchange_attention)
@@ -79,15 +81,54 @@ def init_norm(kind: str, d: int, dtype=jnp.float32) -> Params:
 # rotary position embeddings
 # ---------------------------------------------------------------------------
 
-def rope_tables(positions: jnp.ndarray, head_dim: int, theta: float):
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor: 0.1 · mscale · ln(factor) + 1 (1 unscaled)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(head_dim: int, theta: float, y) -> Tuple[np.ndarray,
+                                                             float]:
+    """(inverse frequencies [hd/2], cos/sin factor) of YaRN rope ``y``
+    (a ``YaRNCfg``), in DeepSeek-V2's form: each frequency blends the
+    original one and the ``factor``-interpolated one by a linear ramp
+    between the correction dims of ``beta_fast`` and ``beta_slow``."""
+    half = head_dim // 2
+    extra = 1.0 / theta ** (np.arange(0, head_dim, 2) / head_dim)
+    inter = extra / y.factor
+
+    def corr_dim(rotations):
+        return (head_dim * math.log(y.original_max_position_embeddings
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(corr_dim(y.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(y.beta_slow)), head_dim - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0, 1)
+    keep = 1.0 - ramp                       # 1: the original frequency
+    inv = inter * (1 - keep) + extra * keep
+    factor = (yarn_mscale(y.factor, y.mscale)
+              / yarn_mscale(y.factor, y.mscale_all_dim))
+    return inv.astype(np.float32), float(factor)
+
+
+def rope_tables(positions: jnp.ndarray, head_dim: int, theta: float,
+                scaling=None):
     """cos/sin tables for given (possibly sharded) integer positions.
 
     positions: [..., N] int32 global positions → ([..., N, hd/2], ...) f32.
+    ``scaling``: a ``YaRNCfg`` (YaRN frequencies and cos/sin factor), or
+    None for plain rope.
     """
     half = head_dim // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    if scaling is None:
+        freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+        factor = 1.0
+    else:
+        freqs, factor = yarn_frequencies(head_dim, theta, scaling)
+        freqs = jnp.asarray(freqs)
     ang = positions.astype(jnp.float32)[..., None] * freqs
-    return jnp.cos(ang), jnp.sin(ang)
+    if factor == 1.0:
+        return jnp.cos(ang), jnp.sin(ang)
+    return jnp.cos(ang) * factor, jnp.sin(ang) * factor
 
 
 def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:

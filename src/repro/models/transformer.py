@@ -50,6 +50,9 @@ Params = Dict[str, Any]
 
 def _attn_spec(cfg: ModelConfig, *, window: Optional[int] = None,
                causal: Optional[bool] = None, use_rope: bool = True) -> AttnSpec:
+    if cfg.rope_scaling is not None:
+        raise NotImplementedError("YaRN rope scaling is implemented for "
+                                  "latent (MLA) attention only")
     return AttnSpec(
         n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=cfg.hd,
         causal=cfg.causal if causal is None else causal,
@@ -186,7 +189,8 @@ def _apply_moe_layer(p: Params, x, cfg: ModelConfig, xcfg, positions,
         h = mla_mod.mla_block(p["attn"],
                               apply_norm(cfg.norm_type, p["ln1"], x),
                               cfg.n_heads, cfg.mla, xcfg,
-                              positions=positions, rope_theta=cfg.rope_theta)
+                              positions=positions, rope_theta=cfg.rope_theta,
+                              rope_scaling=cfg.rope_scaling)
     else:
         h = attention_block(p["attn"],
                             apply_norm(cfg.norm_type, p["ln1"], x),
@@ -195,8 +199,20 @@ def _apply_moe_layer(p: Params, x, cfg: ModelConfig, xcfg, positions,
     hin = apply_norm(cfg.norm_type, p["ln2"], x)
     if dense_mlp:
         return x + apply_mlp(p["mlp"], hin, cfg.act), 0.0
-    y, aux = moe_mod.apply_moe(p["moe"], hin, cfg.moe, cfg.act)
+    y, aux, _ = moe_mod.apply_moe(p["moe"], hin, cfg.moe, cfg.act)
     return x + y, aux
+
+
+def _moe_mlp(lp: Params, x, cfg: ModelConfig, dense_mlp: bool,
+             normed: bool = False):
+    """The feed-forward half of a MoE-family layer on ``x`` (normed by
+    ``ln2`` unless ``normed``) → (output, tokens each held expert
+    computed [n_held], or None for the leading dense layers)."""
+    hin = x if normed else apply_norm(cfg.norm_type, lp["ln2"], x)
+    if dense_mlp:
+        return apply_mlp(lp["mlp"], hin, cfg.act), None
+    y, _, tokens = moe_mod.apply_moe(lp["moe"], hin, cfg.moe, cfg.act)
+    return y, tokens
 
 
 # --- hymba (parallel attention ‖ mamba heads) ------------------------------
@@ -571,7 +587,8 @@ def _image_memory(batch, cfg: ModelConfig, xcfg):
 # decode
 # ---------------------------------------------------------------------------
 
-def _scan_decode_layers(body_fn, x, params_stack, cache_stack):
+def _scan_decode_layers(body_fn, x, params_stack, cache_stack,
+                        with_ys: bool = False):
     """Layer scan for decode with the stacked cache in the CARRY.
 
     Scanning the cache as xs with updated ys duplicates every cache buffer
@@ -579,7 +596,9 @@ def _scan_decode_layers(body_fn, x, params_stack, cache_stack):
     XLA update the single stacked buffer in place inside the while loop;
     per layer we dynamic-slice one layer's cache out and write it back.
 
-    body_fn(x, layer_params, layer_cache) → (x, new_layer_cache).
+    body_fn(x, layer_params, layer_cache) → (x, new_layer_cache[, per-layer
+    output]); with ``with_ys`` the per-layer outputs are returned stacked
+    as a third value.
     """
     import jax.tree_util as jtu
 
@@ -588,14 +607,16 @@ def _scan_decode_layers(body_fn, x, params_stack, cache_stack):
         c = jtu.tree_map(
             lambda t: jax.lax.dynamic_index_in_dim(t, i, 0, keepdims=False),
             cache)
-        y, nc = body_fn(xc, lp, c)
+        y, nc, *out = body_fn(xc, lp, c)
         cache = jtu.tree_map(
             lambda t, u: jax.lax.dynamic_update_index_in_dim(
                 t, u.astype(t.dtype), i, 0), cache, nc)
-        return (y, cache, i + 1), None
+        return (y, cache, i + 1), (out[0] if with_ys else None)
 
-    (x, cache_stack, _), _ = jax.lax.scan(
+    (x, cache_stack, _), ys = jax.lax.scan(
         body, (x, cache_stack, jnp.asarray(0, jnp.int32)), params_stack)
+    if with_ys:
+        return x, cache_stack, ys
     return x, cache_stack
 
 
@@ -608,8 +629,11 @@ def _zeros_in_memory(tree):
     return jax.lax.optimization_barrier(tree)
 
 
-def init_decode_cache(cfg: ModelConfig, batch: int, seq: int) -> Params:
-    """Cache pytree with stacked leading layer/group dims (scan layout)."""
+def init_decode_cache(cfg: ModelConfig, batch: int, seq: int,
+                      paged: bool = False) -> Params:
+    """Cache pytree with stacked leading layer/group dims (scan layout).
+    ``paged``: the page pool's layout (batch = pages, seq = page size),
+    which pads a latent cache's ``k_pe`` to whole lanes."""
     dtype = cfg.jdtype
     fam = cfg.family
 
@@ -628,7 +652,8 @@ def init_decode_cache(cfg: ModelConfig, batch: int, seq: int) -> Params:
         fd = cfg.moe.first_dense_layers
         if cfg.mla is not None:
             def mlac(n):
-                c = mla_mod.init_mla_cache(batch, seq, cfg.mla, dtype)
+                c = mla_mod.init_mla_cache(batch, seq, cfg.mla, dtype,
+                                           paged=paged)
                 return _zeros_in_memory(jax.tree_util.tree_map(
                     lambda l: jnp.stack([l] * n), c))
             return {"first": mlac(fd), "kv": mlac(cfg.n_layers - fd)}
@@ -703,21 +728,14 @@ def decode_step(params: Params, batch: Dict[str, jnp.ndarray], cache: Params,
                     h, nc = mla_mod.mla_decode(
                         lp["attn"], apply_norm(cfg.norm_type, lp["ln1"], xc),
                         cfg.n_heads, cfg.mla, xcfg, c, cache_index,
-                        rope_theta=cfg.rope_theta)
+                        rope_theta=cfg.rope_theta,
+                        rope_scaling=cfg.rope_scaling)
                     xc = xc + h
-                    hin = apply_norm(cfg.norm_type, lp["ln2"], xc)
-                    if dense_mlp:
-                        y = apply_mlp(lp["mlp"], hin, cfg.act)
-                    else:
-                        y, _ = moe_mod.apply_moe(lp["moe"], hin, cfg.moe, cfg.act)
-                    return xc + y, nc
-                mlp_fn = ((lambda h: apply_mlp(lp["mlp"], h, cfg.act))
-                          if dense_mlp else
-                          (lambda h: moe_mod.apply_moe(lp["moe"], h, cfg.moe,
-                                                       cfg.act)))
-                return _apply_attn_mlp_decode(lp, xc, cfg, xcfg,
-                                              _attn_spec(cfg), c, cache_index,
-                                              mlp_fn=mlp_fn)
+                    return xc + _moe_mlp(lp, xc, cfg, dense_mlp)[0], nc
+                return _apply_attn_mlp_decode(
+                    lp, xc, cfg, xcfg, _attn_spec(cfg), c, cache_index,
+                    mlp_fn=lambda h: _moe_mlp(lp, h, cfg, dense_mlp,
+                                              normed=True)[0])
             return body
         x, nfirst = _scan_decode_layers(make_body(True), x,
                                         params["first_layers"],
@@ -803,52 +821,91 @@ def _apply_attn_mlp_decode_paged(p: Params, x, cfg: ModelConfig, xcfg,
     return x + h2, new_cache
 
 
+def _moe_layer_decode_paged(p: Params, x, cfg: ModelConfig, xcfg, cache,
+                            page_table, lengths, dense_mlp: bool):
+    """One MoE-family layer stepped against its paged pool slice: latent
+    pages (MLA) or K/V pages, then the dense or held-experts MLP →
+    (x, new cache, tokens each held expert computed or None)."""
+    xin = apply_norm(cfg.norm_type, p["ln1"], x)
+    if cfg.mla is not None:
+        h, nc = mla_mod.mla_decode_paged(
+            p["attn"], xin, cfg.n_heads, cfg.mla, cache, page_table,
+            lengths, rope_theta=cfg.rope_theta,
+            rope_scaling=cfg.rope_scaling)
+    else:
+        h, nc = attention_decode_paged(p["attn"], xin, _attn_spec(cfg), xcfg,
+                                       cache, page_table, lengths)
+    x = x + h
+    y, tokens = _moe_mlp(p, x, cfg, dense_mlp)
+    return x + y, nc, tokens
+
+
 def supports_page_pool(cfg: ModelConfig) -> bool:
-    """Paged decode covers the plain dense stack: one homogeneous KV cache
-    per layer, no sliding-window alternation (gemma local/global needs
-    per-page window masks) and no per-slot int8 cache (cold pages quantize
-    through the transport codecs instead, in ``repro.serving.pages``)."""
-    return (cfg.family == "dense" and not cfg.local_global
+    """Paged decode covers the plain dense stack and the MoE family (K/V
+    pages, or latent pages of ``c_kv`` and ``k_pe`` under MLA): one
+    homogeneous cache per layer, no sliding-window alternation (gemma
+    local/global needs per-page window masks) and no per-slot int8 cache
+    (cold pages quantize through the transport codecs instead, in
+    ``repro.serving.pages``)."""
+    return (cfg.family in ("dense", "moe") and not cfg.local_global
             and not cfg.kv_quant)
 
 
 def init_page_pool(cfg: ModelConfig, n_pages: int, page_size: int) -> Params:
-    """Shared paged KV pool: same pytree as ``init_decode_cache`` but the
+    """Shared paged pool: same pytree as ``init_decode_cache`` but the
     (batch, seq) axes become (page, in-page position) — leaves are
-    ``[n_layers, n_pages, page_size, Hk, dh]``.  Requests address it through
-    per-row page tables; physical rows are interchangeable."""
+    ``[n_layers, n_pages, page_size, Hk, dh]`` (K and V), or under MLA
+    ``[n_layers, n_pages, page_size, r]`` (``c_kv``) and ``[..., 128]``
+    (``k_pe``, dr of them used, the rest zeros: ``mla.PE_LANES``).
+    Requests address it through per-row page tables; physical rows are
+    interchangeable."""
     if not supports_page_pool(cfg):
         raise ValueError(f"family {cfg.family!r} (local_global="
                          f"{cfg.local_global}, kv_quant={cfg.kv_quant}) "
                          f"has no paged decode path")
-    return init_decode_cache(cfg, n_pages, page_size)
+    return init_decode_cache(cfg, n_pages, page_size, paged=True)
 
 
 def decode_step_paged(params: Params, batch: Dict[str, jnp.ndarray],
                       pool: Params, page_table: jnp.ndarray,
                       lengths: jnp.ndarray, cfg: ModelConfig,
-                      xcfg: ExchangeConfig) -> Tuple[jnp.ndarray, Params]:
+                      xcfg: ExchangeConfig):
     """One-token step for every row against the shared paged pool.
 
     batch: {"tokens": [S, 1]}; ``page_table`` [S, max_pages] int32 maps each
     row's logical blocks to pool pages; ``lengths`` [S] int32 is each row's
     current sequence length (= this step's write position).  Returns
-    (logits [S, 1, V], updated pool).
+    (logits [S, 1, V], updated pool, the tokens each held expert computed
+    [moe_layers, n_held] int32, or None for a model without experts).
     """
     if not supports_page_pool(cfg):
         raise ValueError(f"family {cfg.family!r} has no paged decode path")
     tokens = batch["tokens"]
     x = embed(params["embed"], tokens, scale_by_sqrt_d=cfg.embed_scale)
-
-    def body(xc, lp, c):
-        return _apply_attn_mlp_decode_paged(lp, xc, cfg, xcfg,
-                                            _attn_spec(cfg), c,
-                                            page_table, lengths)
-    x, nkv = _scan_decode_layers(body, x, params["layers"], pool["kv"])
+    counts = None
+    if cfg.family == "moe":
+        def make_body(dense_mlp):
+            def body(xc, lp, c):
+                return _moe_layer_decode_paged(lp, xc, cfg, xcfg, c,
+                                               page_table, lengths, dense_mlp)
+            return body
+        x, nfirst = _scan_decode_layers(make_body(True), x,
+                                        params["first_layers"], pool["first"])
+        x, nkv, counts = _scan_decode_layers(make_body(False), x,
+                                             params["layers"], pool["kv"],
+                                             with_ys=True)
+        new_pool = {"first": nfirst, "kv": nkv}
+    else:
+        def body(xc, lp, c):
+            return _apply_attn_mlp_decode_paged(lp, xc, cfg, xcfg,
+                                                _attn_spec(cfg), c,
+                                                page_table, lengths)
+        x, nkv = _scan_decode_layers(body, x, params["layers"], pool["kv"])
+        new_pool = {"kv": nkv}
     x = apply_norm(cfg.norm_type, params["final_norm"], x)
     head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
     logits = unembed(head, x, final_softcap=cfg.final_softcap)
-    return logits, {"kv": nkv}
+    return logits, new_pool, counts
 
 
 # single-pass prefill is defined for the attention-cached families; the
@@ -918,22 +975,14 @@ def prefill(params: Params, batch: Dict[str, jnp.ndarray], cache: Params,
                     h, nc = mla_mod.mla_prefill(
                         lp["attn"], apply_norm(cfg.norm_type, lp["ln1"], xc),
                         cfg.n_heads, cfg.mla, xcfg, c, positions=positions,
-                        rope_theta=cfg.rope_theta)
+                        rope_theta=cfg.rope_theta,
+                        rope_scaling=cfg.rope_scaling)
                     xc = xc + h
-                    hin = apply_norm(cfg.norm_type, lp["ln2"], xc)
-                    if dense_mlp:
-                        y = apply_mlp(lp["mlp"], hin, cfg.act)
-                    else:
-                        y, _ = moe_mod.apply_moe(lp["moe"], hin, cfg.moe,
-                                                 cfg.act)
-                    return xc + y, nc
-                mlp_fn = ((lambda h: apply_mlp(lp["mlp"], h, cfg.act))
-                          if dense_mlp else
-                          (lambda h: moe_mod.apply_moe(lp["moe"], h, cfg.moe,
-                                                       cfg.act)))
-                return _apply_attn_mlp_prefill(lp, xc, cfg, xcfg,
-                                               _attn_spec(cfg), positions, c,
-                                               mlp_fn=mlp_fn)
+                    return xc + _moe_mlp(lp, xc, cfg, dense_mlp)[0], nc
+                return _apply_attn_mlp_prefill(
+                    lp, xc, cfg, xcfg, _attn_spec(cfg), positions, c,
+                    mlp_fn=lambda h: _moe_mlp(lp, h, cfg, dense_mlp,
+                                              normed=True)[0])
             return body
         x, nfirst = _scan_decode_layers(make_body(True), x,
                                         params["first_layers"],

@@ -332,6 +332,13 @@ class PagedPool:
         self.cold_codec = cold_codec
         # +1 page: the trash page (id == n_pages) absorbing idle-row writes
         self.pool = session.init_page_pool(n_pages + 1, page_size)
+        if cold_horizon is not None and "c_kv" in self.pool["kv"]:
+            raise NotImplementedError(
+                "cold-page quantization has no latent (MLA) page layout; "
+                "serve this model with cold_horizon=None")
+        # a model with routed experts reports the tokens each held expert
+        # computed, per chunk (read on the host only when traced)
+        self.has_experts = session.cfg.moe is not None
         self.trash = n_pages
         self.alloc = PageAllocator(n_pages)
         self.prefix = (PrefixCache(self.alloc, max_entries=max_entries)
@@ -635,8 +642,12 @@ class PagedPool:
         tracer, ``last_chunk`` holds the chunk's phase stamps and counts
         (``engine.chunk_record``, plus ``pages_valid``: distinct pages
         holding valid tokens of busy rows after the chunk, ``pages_held``:
-        pages off the free list, and ``kv_blocks_walked`` of
-        ``kv_blocks_table``: the paged kernel's blocks at the last step)."""
+        pages off the free list, ``kv_blocks_walked`` of
+        ``kv_blocks_table``: the paged kernel's blocks at the last step,
+        and for a model with routed experts ``experts_touched`` of
+        ``experts_held``: held experts that computed at least one token,
+        summed over MoE layers and steps, of all of them, and
+        ``expert_tokens``: the (token, expert) pairs they computed)."""
         traced = self.tracer is not None
         t0 = time.perf_counter()
         for row, act in enumerate(self.slots):
@@ -645,11 +656,12 @@ class PagedPool:
         ps = self.page_size
         caps = np.asarray([max(len(p), 1) * ps - 1 for p in self.row_pages],
                           np.int32)
-        toks, self.pool, self.lengths, self.keys = \
+        toks, self.pool, self.lengths, self.keys, *experts = \
             self.session.paged_decode_chunk(
                 self.pool, jnp.asarray(self.page_table), jnp.asarray(caps),
                 self.tok, self.lengths, self.keys, self.temps,
-                n_steps=n_steps, plan=self.plan)
+                n_steps=n_steps, plan=self.plan,
+                with_counts=traced and self.has_experts)
         self.tok = toks[:, -1]
         waited = self.clock() if traced else 0.0
         out = np.asarray(toks)
@@ -678,17 +690,22 @@ class PagedPool:
                 pages_valid=len(valid),
                 pages_held=self.n_pages - len(self.alloc.free),
                 **self._kv_blocks(last))
+            if experts:
+                tokens = np.asarray(experts[0])     # [steps, layers, held]
+                rec["counts"].update(
+                    experts_touched=int((tokens > 0).sum()),
+                    experts_held=int(tokens.size),
+                    expert_tokens=int(tokens.sum()))
             self.last_chunk = rec
         return wall_ms
 
     def _kv_blocks(self, lengths: np.ndarray) -> Dict[str, int]:
         """Blocks the paged decode kernel walks for rows of ``lengths``
         (``kv_blocks_walked``), and the blocks of the whole table
-        (``kv_blocks_table``)."""
-        from repro.kernels.flash_decode.paged import pages_per_block
-        k = self.pool["kv"]["k"]
-        ppb = pages_per_block(self.page_size, k.shape[-2] * k.shape[-1],
-                              self.max_pages, k.dtype.itemsize)
+        (``kv_blocks_table``): the K/V kernel's or the latent kernel's,
+        whichever reads this pool's leaves."""
+        from repro.kernels.dispatch import paged_pages_per_block
+        ppb = paged_pages_per_block(self.pool, self.max_pages)
         bk = ppb * self.page_size
         return {"kv_blocks_walked": int(sum(-(-lengths // bk))),
                 "kv_blocks_table": self.n_rows * -(-self.max_pages // ppb)}

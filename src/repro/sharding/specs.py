@@ -94,7 +94,7 @@ def _param_gb(cfg: ModelConfig) -> float:
     per_layer = 4 * d * d + 3 * d * f
     if cfg.moe:
         m = cfg.moe
-        per_layer = 4 * d * d + 3 * d * m.d_ff_expert * (m.n_experts + m.n_shared)
+        per_layer = 4 * d * d + 3 * d * m.d_ff_expert * (m.n_held + m.n_shared)
     total = nl * per_layer + 2 * V * d
     return total * 2 / 1e9
 

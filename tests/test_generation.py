@@ -99,9 +99,8 @@ def test_generate_parity_families(arch):
     prompt = _prompt(B=1, T0=4, seed=len(arch))
     ref = legacy_generate(params, prompt, 4, cfg, xcfg)
     fn = gen.build_generate_fn(cfg, xcfg, n_new=4)
-    # MoE capacity routing is seq-len dependent → auto keeps it scanned
-    want = ("single_pass"
-            if tfm.supports_prefill(cfg) and cfg.moe is None else "scan")
+    # dropless MoE routes a prompt in one pass as it does token by token
+    want = "single_pass" if tfm.supports_prefill(cfg) else "scan"
     assert fn.prefill_mode == want
     got = fn(params, prompt, {}, jax.random.key(0))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
@@ -127,8 +126,8 @@ def test_prefill_matches_forward_last_logits(dense, plan):
 
 
 def test_moe_single_pass_prefill_matches_forward():
-    """MoE single-pass prefill is opt-in (capacity routing is seq-len
-    dependent) and must reproduce the full forward's routing semantics."""
+    """MoE single-pass prefill reproduces the full forward (dropless
+    routing: the same math at any sequence length)."""
     cfg = _cfg("deepseek-v2-236b")
     params = registry.init_params(cfg, seed=0)
     xcfg = ExecutionPlan.local().to_exchange_config()

@@ -6,13 +6,15 @@ whole programs: the ViT forward under ``prism_sim``, for the kernel in it,
 and the prefill, for what only the TPU compiler does to it.
 
 Widths: internlm2-1.8b (H=16, Hk=8, dh=128, caches up to 4096 positions,
-16-position pages) and ViT-B/16 (197 tokens padded, d=768, 12 heads).
+16-position pages), DeepSeek-V2-Lite's latent pages (H=16, r=512, rope 64)
+and ViT-B/16 (197 tokens padded, d=768, 12 heads).
 """
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.flash_decode.latent import flash_decode_latent_pallas
 from repro.kernels.flash_decode.ops import flash_decode_op
 from repro.kernels.flash_decode.paged import flash_decode_paged_op
 from repro.kernels.prism_attention.ops import prism_attention_op
@@ -70,6 +72,21 @@ def test_paged_flash_decode_compiles(one_chip, page_size, B):
         _shape(one_chip, (B, 1, H, dh)), pool, pool,
         _shape(one_chip, (B, pages_per_row), jnp.int32),
         _shape(one_chip, (B,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+# DeepSeek-V2-Lite latent pages: c_kv 512, k_pe 64 padded to 128 lanes;
+# B = 32: a decode chunk's rows over 192-page tables; B = 1: a suffix scan
+@pytest.mark.parametrize("B", [32, 1])
+def test_paged_latent_decode_compiles(one_chip, B):
+    H, r, dr, dp, ps, MP = 16, 512, 64, 128, 16, 192
+    n_pages = 4096 + 1
+    text = _compile(
+        lambda q, qp, c, pe, pt, n: flash_decode_latent_pallas(
+            q, qp, c, pe, pt, n, scale=0.1, interpret=False),
+        _shape(one_chip, (B, H, r)), _shape(one_chip, (B, H, dr)),
+        _shape(one_chip, (n_pages, ps, r)), _shape(one_chip, (n_pages, ps, dp)),
+        _shape(one_chip, (B, MP), jnp.int32), _shape(one_chip, (B,), jnp.int32))
     assert "tpu_custom_call" in text
 
 
