@@ -392,13 +392,14 @@ def build_paged_admit_fn(cfg: ModelConfig) -> Callable:
     def admit(pool, tok, lengths, keys, temps, req_cache, page_ids, row,
               tok0, length0, key0, temp0):
         def scatter(p, r):
-            # p: [L, P, ps, Hk, dh] pool leaf; r: [L, 1, P0*ps, Hk, dh]
-            # (a latent pool's k_pe is wider than the request's: padded)
+            # p: [L, P, ps, W] pool leaf; r: [L, 1, P0*ps, Hk, dh] (K/V,
+            # W = Hk·dh) or [L, 1, P0*ps, w] (latent; a k_pe is narrower
+            # than the pool's: padded)
             ps = p.shape[2]
             P0 = r.shape[2] // ps
-            r = pad_lanes(r, p.shape[-1]).astype(p.dtype)
-            r = r.reshape(r.shape[0], P0, ps, *r.shape[3:])
-            return p.at[:, page_ids].set(r)
+            r = r.reshape(r.shape[0], P0, ps, -1)
+            return p.at[:, page_ids].set(
+                pad_lanes(r, p.shape[-1]).astype(p.dtype))
 
         pool = jax.tree_util.tree_map(scatter, pool, req_cache)
         tok = tok.at[row].set(tok0[0, 0])

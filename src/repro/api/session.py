@@ -628,7 +628,7 @@ class InferenceSession:
     # -- paged-pool serving primitives (used by repro.serving.pages) ---------
 
     def init_page_pool(self, n_pages: int, page_size: int):
-        """Shared paged KV pool (``[n_layers, n_pages, page_size, Hk, dh]``
+        """Shared paged KV pool (``[n_layers, n_pages, page_size, Hk·dh]``
         leaves) — the state the paged admission/decode executables operate
         on.  Raises for families without a paged decode path."""
         from repro.models import transformer as tfm

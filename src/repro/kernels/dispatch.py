@@ -188,27 +188,32 @@ def decode_attention(q: jnp.ndarray,        # [B, 1, H, dh]
 
 
 def decode_attention_paged(q: jnp.ndarray,           # [B, 1, H, dh]
-                           k_pool: jnp.ndarray,      # [P, ps, Hk, dh]
+                           k_pool: jnp.ndarray,      # [L, P, ps, Hk·dh]
                            v_pool: jnp.ndarray,
+                           layer,                    # int32 scalar
                            page_table: jnp.ndarray,  # [B, max_pages] int32
                            cache_len,                # [B] — valid prefix
                            *,
                            logit_softcap: Optional[float] = None,
                            scale: Optional[float] = None) -> jnp.ndarray:
-    """Single-token attention against a *paged* KV pool: each request's
-    cache is the concatenation of the pool pages named by its page-table
-    row, masked to the valid ``cache_len`` prefix.
+    """Single-token attention against layer ``layer`` of a *paged* KV
+    pool, every layer's pages stacked: each request's cache is the
+    concatenation of the layer's pages named by its page-table row, masked
+    to the valid ``cache_len`` prefix.
 
     Reference path: materialize the gather with ``jnp.take`` and run the
     exact dense reference (CPU/interpret parity oracle).  Pallas path: the
     paged flash-decode kernel copies each row's valid pages through the
     scalar-prefetched table — no gather is ever materialized.
     """
+    B, dh = q.shape[0], q.shape[-1]
+    heads = jax.ShapeDtypeStruct((k_pool.shape[-1] // dh, dh), k_pool.dtype)
     if _use_pallas():
-        if _decode_tiles(k_pool, whole_rows=True):
+        if _decode_tiles(heads, whole_rows=True):
             from repro.kernels.flash_decode.paged import flash_decode_paged_op
-            o, m, l = flash_decode_paged_op(q, k_pool, v_pool, page_table,
-                                            cache_len, scale=scale,
+            o, m, l = flash_decode_paged_op(q, k_pool, v_pool, layer,
+                                            page_table, cache_len,
+                                            scale=scale,
                                             softcap=logit_softcap,
                                             interpret=_interpret())
             out = o / jnp.maximum(l, 1e-38)[..., None]            # [B, H, dh]
@@ -216,22 +221,24 @@ def decode_attention_paged(q: jnp.ndarray,           # [B, 1, H, dh]
         _fallback("decode_attention_paged", "head_dim")
     from repro.kernels.flash_decode.ops import validity_mask
     from repro.kernels.flash_decode.paged import gather_pages
-    k = gather_pages(k_pool, page_table)
-    v = gather_pages(v_pool, page_table)
-    valid = validity_mask(q.shape[0], k.shape[1], cache_len)
+    k = gather_pages(k_pool, layer, page_table).reshape(B, -1, *heads.shape)
+    v = gather_pages(v_pool, layer, page_table).reshape(B, -1, *heads.shape)
+    valid = validity_mask(B, k.shape[1], cache_len)
     return ref_attn.reference_attention(
         q, k, v, kv_mask=valid, logit_softcap=logit_softcap, scale=scale)
 
 
 def decode_attention_latent_paged(q_lat: jnp.ndarray,     # [B, 1, H, r]
                                   q_pe: jnp.ndarray,      # [B, 1, H, dr]
-                                  c_pool: jnp.ndarray,    # [P, ps, r]
-                                  pe_pool: jnp.ndarray,   # [P, ps, dp >= dr]
+                                  c_pool: jnp.ndarray,    # [L, P, ps, r]
+                                  pe_pool: jnp.ndarray,   # [L, P, ps, dp]
+                                  layer,                  # int32 scalar
                                   page_table: jnp.ndarray,  # [B, max_pages]
                                   cache_len,              # [B] valid prefix
                                   *, scale: float) -> jnp.ndarray:
-    """Absorbed latent (MLA) attention of one token against a *paged*
-    latent pool → the latent output [B, 1, H, r] (``W_uv`` comes after).
+    """Absorbed latent (MLA) attention of one token against layer
+    ``layer`` of a *paged* latent pool (every layer's pages stacked) →
+    the latent output [B, 1, H, r] (``W_uv`` comes after).
 
     Pallas path: the paged latent kernel copies each row's valid
     ``c_kv``/``k_pe`` pages through the scalar-prefetched table.  Compiled,
@@ -246,13 +253,13 @@ def decode_attention_latent_paged(q_lat: jnp.ndarray,     # [B, 1, H, r]
     if _use_pallas():
         if _interpret() or c_pool.shape[-1] % 128 == 0:
             o, m, l = latent.flash_decode_latent_pallas(
-                q_lat[:, 0], q_pe[:, 0], c_pool, pe_pool, page_table,
+                q_lat[:, 0], q_pe[:, 0], c_pool, pe_pool, layer, page_table,
                 lengths, scale=scale, interpret=_interpret())
             out = o / jnp.maximum(l, 1e-38)[..., None]            # [B, H, r]
             return out[:, None].astype(q_lat.dtype)
         _fallback("decode_attention_latent_paged", "latent_dim")
     out = latent.flash_decode_latent_ref(q_lat[:, 0], q_pe[:, 0], c_pool,
-                                         pe_pool, page_table, lengths,
+                                         pe_pool, layer, page_table, lengths,
                                          scale=scale)
     return out[:, None].astype(q_lat.dtype)
 
@@ -268,9 +275,9 @@ def paged_pages_per_block(pool, max_pages: int) -> int:
         c, pe = leaves["c_kv"], leaves["k_pe"]
         return pages_per_block(c.shape[-2], c.shape[-1] + pe.shape[-1],
                                max_pages, c.dtype.itemsize)
-    k = leaves["k"]
-    return pages_per_block(k.shape[-3], k.shape[-2] * k.shape[-1],
-                           max_pages, k.dtype.itemsize)
+    k = leaves["k"]                 # [L, P, ps, Hk·dh]
+    return pages_per_block(k.shape[-2], k.shape[-1], max_pages,
+                           k.dtype.itemsize)
 
 
 # ---------------------------------------------------------------------------

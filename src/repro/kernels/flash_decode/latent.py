@@ -1,11 +1,12 @@
 """Paged latent-attention decode (MLA, absorbed form) through a page table.
 
-Each layer's cache is a pool of latent pages: ``c_kv`` ``[P, ps, r]`` and
-the shared rotary key ``k_pe`` ``[P, ps, dp]`` (its ``dr`` columns
-zero-padded to ``dp``, a lane multiple: a page copy spans whole lanes);
-a request owns a row of a
-``[B, max_pages]`` page table, as for K/V pages.  Every query head scores
-against the one latent page, in the absorbed form:
+The cache is a pool of latent pages, every layer's stacked: ``c_kv``
+``[L, P, ps, r]`` and the shared rotary key ``k_pe`` ``[L, P, ps, dp]``
+(its ``dr`` columns zero-padded to ``dp``, a lane multiple: a page copy
+spans whole lanes); a request owns a row of a ``[B, max_pages]`` page
+table, as for K/V pages, and the kernel reads the pages of the layer
+index it is given.  Every query head scores against the one latent page,
+in the absorbed form:
 
   s_h = (q_nope_h · W_uk_h) · c_kvᵀ + q_pe_h · k_peᵀ ,   o_h = p_h · c_kv
 
@@ -17,10 +18,10 @@ The structure is the K/V kernel's (``paged.py``): grid (B, n_blocks), one
 step per row and block of ``pages_per_block`` pages; ``c_kv`` and
 ``k_pe`` stay in HBM and each page is one copy of each into a
 double-buffered VMEM block, the next block's copies (this row's next,
-else the next row's first) started before this one waits; page table and
-valid lengths ride in as scalar prefetch; a row walks only
-``ceil(len / block tokens)`` blocks and copies only its pages below
-``ceil(len / ps)``.  Positions at or past a row's length are *selected*
+else the next row's first) started before this one waits; page table,
+valid lengths and the layer index ride in as scalar prefetch; a row
+walks only ``ceil(len / block tokens)`` blocks and copies only its pages
+below ``ceil(len / ps)``.  Positions at or past a row's length are *selected*
 away, never multiplied by zero: they may hold NaN.
 """
 from __future__ import annotations
@@ -39,16 +40,17 @@ from repro.kernels.flash_decode.paged import gather_pages, pages_per_block
 
 def flash_decode_latent_ref(q_lat: jnp.ndarray,      # [B, H, r]
                             q_pe: jnp.ndarray,       # [B, H, dr]
-                            c_pool: jnp.ndarray,     # [P, ps, r]
-                            pe_pool: jnp.ndarray,    # [P, ps, dp >= dr]
+                            c_pool: jnp.ndarray,     # [L, P, ps, r]
+                            pe_pool: jnp.ndarray,    # [L, P, ps, dp >= dr]
+                            layer,                   # int32 scalar
                             page_table: jnp.ndarray,  # [B, MP] int32
                             lengths: jnp.ndarray,    # [B] int32
                             *, scale: float) -> jnp.ndarray:
     """``jnp.take`` gather + masked softmax in float32 → o [B, H, r]."""
     dr = q_pe.shape[-1]
-    c = gather_pages(c_pool[..., None, :], page_table)[:, :, 0].astype(
+    c = gather_pages(c_pool, layer, page_table).astype(
         jnp.float32)                                          # [B, S, r]
-    pe = gather_pages(pe_pool[..., None, :dr], page_table)[:, :, 0].astype(
+    pe = gather_pages(pe_pool, layer, page_table)[..., :dr].astype(
         jnp.float32)
     s = (jnp.einsum("bhr,bsr->bhs", q_lat.astype(jnp.float32), c)
          + jnp.einsum("bhd,bsd->bhs", q_pe.astype(jnp.float32), pe)) * scale
@@ -58,7 +60,7 @@ def flash_decode_latent_ref(q_lat: jnp.ndarray,      # [B, H, r]
     return jnp.einsum("bhs,bsr->bhr", p, jnp.where(valid[..., None], c, 0.0))
 
 
-def _latent_kernel(len_ref, pt_ref, q_ref, qpe_ref, c_hbm, pe_hbm,
+def _latent_kernel(len_ref, pt_ref, layer_ref, q_ref, qpe_ref, c_hbm, pe_hbm,
                    o_ref, m_ref, l_ref, c_buf, pe_buf, sems, slot_ref, *,
                    scale: float, max_pages: int):
     b, j = pl.program_id(0), pl.program_id(1)
@@ -75,12 +77,14 @@ def _latent_kernel(len_ref, pt_ref, q_ref, qpe_ref, c_hbm, pe_hbm,
         ``slot``: its pages below ceil(len / ps), c_kv and k_pe."""
         first = blk * ppb
         n = jnp.minimum((len_ref[row] + ps - 1) // ps - first, ppb)
+        layer = layer_ref[0]
 
         def one(p, carry):
             page = pt_ref[row * max_pages + first + p]
             for i, (src, dst) in enumerate(((c_hbm, c_buf),
                                             (pe_hbm, pe_buf))):
-                getattr(pltpu.make_async_copy(src.at[page], dst.at[slot, p],
+                getattr(pltpu.make_async_copy(src.at[layer, page],
+                                              dst.at[slot, p],
                                               sems.at[i, slot]), method)()
             return carry
 
@@ -137,8 +141,9 @@ def _latent_kernel(len_ref, pt_ref, q_ref, qpe_ref, c_hbm, pe_hbm,
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def flash_decode_latent_pallas(q_lat: jnp.ndarray,      # [B, H, r]
                                q_pe: jnp.ndarray,       # [B, H, dr]
-                               c_pool: jnp.ndarray,     # [P, ps, r]
-                               pe_pool: jnp.ndarray,    # [P, ps, dp]
+                               c_pool: jnp.ndarray,     # [L, P, ps, r]
+                               pe_pool: jnp.ndarray,    # [L, P, ps, dp]
+                               layer,                   # int32 scalar
                                page_table: jnp.ndarray,  # [B, MP] int32
                                lengths: jnp.ndarray,    # [B] int32
                                *, scale: float, interpret: bool = False
@@ -146,13 +151,13 @@ def flash_decode_latent_pallas(q_lat: jnp.ndarray,      # [B, H, r]
                                           jnp.ndarray]:
     """Pallas paged latent decode → (o·l [B, H, r], m [B, H], l [B, H])
     partials, in float32.  Row ``b`` attends to its positions below
-    ``lengths[b]``; its logical page ``p`` is pool page
-    ``page_table[b, p]``.  ``q_lat`` and ``q_pe`` (zero-padded to the
-    pool's ``k_pe`` width) are read in the pool's dtype; the scores
-    accumulate in float32."""
+    ``lengths[b]``; its logical page ``p`` is page ``page_table[b, p]``
+    of layer ``layer`` of the stacked pool.  ``q_lat`` and ``q_pe``
+    (zero-padded to the pool's ``k_pe`` width) are read in the pool's
+    dtype; the scores accumulate in float32."""
     B, H, r = q_lat.shape
     dp = pe_pool.shape[-1]
-    ps = c_pool.shape[1]
+    ps = c_pool.shape[2]
     MP = page_table.shape[1]
     # a page's c_kv and padded k_pe rows count together against a block
     ppb = pages_per_block(ps, r + dp, MP, c_pool.dtype.itemsize)
@@ -162,7 +167,7 @@ def flash_decode_latent_pallas(q_lat: jnp.ndarray,      # [B, H, r]
     lengths = jnp.minimum(lengths, MP * ps).astype(jnp.int32)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, n_blocks),
         in_specs=[pl.BlockSpec((1, H, r), lambda b, j, *_: (b, 0, 0)),
                   pl.BlockSpec((1, H, dp), lambda b, j, *_: (b, 0, 0)),
@@ -186,6 +191,7 @@ def flash_decode_latent_pallas(q_lat: jnp.ndarray,      # [B, H, r]
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(lengths, page_table.astype(jnp.int32).reshape(-1),
+      jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)),
       q_lat.astype(c_pool.dtype),
       jnp.pad(q_pe, ((0, 0), (0, 0), (0, dp - q_pe.shape[-1]))).astype(
           pe_pool.dtype), c_pool, pe_pool)

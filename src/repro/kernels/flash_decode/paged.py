@@ -1,21 +1,25 @@
 """Paged flash-decode: one-token attention gathered through a page table.
 
-The KV cache lives in a shared pool of fixed-size pages
-(``[n_pages, page_size, Hk, dh]``); each request owns a row of a
-``[B, max_pages]`` int32 page table mapping its logical block ``p`` to a
-physical page id.  The reference path materializes the gather with
-``jnp.take``; the Pallas path never materializes it.
+The KV cache lives in a shared pool of fixed-size pages, every layer's
+stacked: ``[n_layers, n_pages, page_size, Hk·dh]``, a page one contiguous
+``[ps, Hk·dh]`` row block, the layout the kernel copies.  Each request
+owns a row of a ``[B, max_pages]`` int32 page table mapping its logical
+block ``p`` to a physical page id.  Every entry point takes the stacked
+pool and a layer index, and reads that layer's pages where they lie: no
+caller slices a layer out.  The reference path materializes the gather
+with ``jnp.take``; the Pallas path never materializes it.
 
 Pallas path: grid (B, n_blocks), one step per row and block of
-``pages_per_block`` pages, every KV head at once.  K/V stay in HBM, viewed
-as [P, ps, Hk·dh] so that a page is one contiguous copy; the page table
-and each row's valid length ride in as scalar prefetch.  Each step copies
-its block's pages into one half of a double-buffered VMEM block, having
-started the next block's copies (this row's next, else the next row's
-first) into the other half.  A row walks ``ceil(len / block tokens)``
-blocks and copies only its pages below ``ceil(len / ps)``: the blocks
-past its length issue no copy and skip their compute, so an idle row
-that points at the trash page costs one block.
+``pages_per_block`` pages, every KV head at once.  K/V stay in HBM; the
+page table, each row's valid length and the layer index ride in as
+scalar prefetch, and page ``p`` of the layer is the copy of
+``pool[layer, p]``.  Each step copies its block's pages into one half of
+a double-buffered VMEM block, having started the next block's copies
+(this row's next, else the next row's first) into the other half.  A row
+walks ``ceil(len / block tokens)`` blocks and copies only its pages below
+``ceil(len / ps)``: the blocks past its length issue no copy and skip
+their compute, so an idle row that points at the trash page costs one
+block.
 
 Validity arrives as each row's valid length from ``ops.valid_span`` — the
 ONE definition of cache validity, shared with the dense op.  Positions at
@@ -37,27 +41,31 @@ from repro.kernels.flash_decode.kernel import (NEG_INF, merge_heads,
 from repro.kernels.flash_decode.ref import flash_decode_ref
 
 
-def gather_pages(pool: jnp.ndarray,         # [P, ps, Hk, dh]
+def gather_pages(pool: jnp.ndarray,         # [L, P, ps, W]
+                 layer,                     # int32 scalar
                  page_table: jnp.ndarray    # [B, MP] int32
-                 ) -> jnp.ndarray:          # [B, MP*ps, Hk, dh]
-    """Materialize a per-request contiguous KV view from the page pool."""
+                 ) -> jnp.ndarray:          # [B, MP*ps, W]
+    """Materialize a per-request contiguous view of layer ``layer``'s
+    pages from the stacked page pool."""
     B, MP = page_table.shape
-    ps = pool.shape[1]
-    return jnp.take(pool, page_table, axis=0).reshape(
-        B, MP * ps, *pool.shape[2:])
+    ps = pool.shape[2]
+    return jnp.take(pool[layer], page_table, axis=0).reshape(
+        B, MP * ps, *pool.shape[3:])
 
 
 def flash_decode_paged_ref(q: jnp.ndarray,           # [B, H, dh]
-                           k_pool: jnp.ndarray,      # [P, ps, Hk, dh]
+                           k_pool: jnp.ndarray,      # [L, P, ps, Hk·dh]
                            v_pool: jnp.ndarray,
+                           layer,                    # int32 scalar
                            page_table: jnp.ndarray,  # [B, MP] int32
                            kv_bias: jnp.ndarray,     # [B, MP*ps] f32
                            *, scale: Optional[float] = None,
                            softcap: Optional[float] = None
                            ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """``jnp.take`` gather + the dense reference math → (o·l, m, l)."""
-    k = gather_pages(k_pool, page_table)
-    v = gather_pages(v_pool, page_table)
+    B, S, dh = page_table.shape[0], kv_bias.shape[1], q.shape[-1]
+    k = gather_pages(k_pool, layer, page_table).reshape(B, S, -1, dh)
+    v = gather_pages(v_pool, layer, page_table).reshape(B, S, -1, dh)
     return flash_decode_ref(q, k, v, kv_bias, scale=scale, softcap=softcap)
 
 
@@ -79,8 +87,8 @@ def pages_per_block(page_size: int, row_width: int, max_pages: int,
                       MAX_BLOCK_TOKENS // page_size))
 
 
-def _paged_kernel(len_ref, pt_ref, q_ref, k_hbm, v_hbm, o_ref, m_ref, l_ref,
-                  k_buf, v_buf, sems, slot_ref, *, scale: float,
+def _paged_kernel(len_ref, pt_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
+                  m_ref, l_ref, k_buf, v_buf, sems, slot_ref, *, scale: float,
                   softcap: Optional[float], max_pages: int):
     b, j = pl.program_id(0), pl.program_id(1)
     n_rows = pl.num_programs(0)
@@ -96,11 +104,13 @@ def _paged_kernel(len_ref, pt_ref, q_ref, k_hbm, v_hbm, o_ref, m_ref, l_ref,
         buffer ``slot``: its pages below ceil(len / ps)."""
         first = blk * ppb
         n = jnp.minimum((len_ref[r] + ps - 1) // ps - first, ppb)
+        layer = layer_ref[0]
 
         def one(p, carry):
             page = pt_ref[r * max_pages + first + p]
             for i, (src, dst) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
-                getattr(pltpu.make_async_copy(src.at[page], dst.at[slot, p],
+                getattr(pltpu.make_async_copy(src.at[layer, page],
+                                              dst.at[slot, p],
                                               sems.at[i, slot]), method)()
             return carry
 
@@ -159,8 +169,9 @@ def _paged_kernel(len_ref, pt_ref, q_ref, k_hbm, v_hbm, o_ref, m_ref, l_ref,
 
 @functools.partial(jax.jit, static_argnames=("scale", "softcap", "interpret"))
 def flash_decode_paged_pallas(q: jnp.ndarray,           # [B, H, dh]
-                              k_pool: jnp.ndarray,      # [P, ps, Hk, dh]
+                              k_pool: jnp.ndarray,      # [L, P, ps, Hk·dh]
                               v_pool: jnp.ndarray,
+                              layer,                    # int32 scalar
                               page_table: jnp.ndarray,  # [B, MP] int32
                               lengths: jnp.ndarray,     # [B] int32
                               *, scale: Optional[float] = None,
@@ -169,14 +180,16 @@ def flash_decode_paged_pallas(q: jnp.ndarray,           # [B, H, dh]
     """Pallas paged flash-decode → (o·l, m, l) partials.
 
     Row ``b`` attends to its positions below ``lengths[b]``; logical page
-    ``p`` of it is physical page ``page_table[b, p]`` of the pool.
+    ``p`` of it is physical page ``page_table[b, p]`` of layer ``layer``
+    of the stacked pool.
     """
     B, H, dh = q.shape
-    P, ps, Hk = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
+    ps, W = k_pool.shape[2], k_pool.shape[3]
     MP = page_table.shape[1]
     scale = (dh ** -0.5) if scale is None else scale
+    Hk = W // dh
     G = H // Hk
-    ppb = pages_per_block(ps, Hk * dh, MP, k_pool.dtype.itemsize)
+    ppb = pages_per_block(ps, W, MP, k_pool.dtype.itemsize)
     n_blocks = -(-MP // ppb)
     # a length past the table walks the whole table: no walk outruns the
     # grid, so every copy a step starts is waited for
@@ -185,12 +198,12 @@ def flash_decode_paged_pallas(q: jnp.ndarray,           # [B, H, dh]
     stat = pl.BlockSpec((1, Hk, G, 1), lambda b, j, *_: (b, 0, 0, 0))
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, n_blocks),
         in_specs=[row, hbm, hbm],
         out_specs=(row, stat, stat),
-        scratch_shapes=[pltpu.VMEM((2, ppb, ps, Hk * dh), k_pool.dtype),
-                        pltpu.VMEM((2, ppb, ps, Hk * dh), v_pool.dtype),
+        scratch_shapes=[pltpu.VMEM((2, ppb, ps, W), k_pool.dtype),
+                        pltpu.VMEM((2, ppb, ps, W), v_pool.dtype),
                         pltpu.SemaphoreType.DMA((2, 2)),
                         pltpu.SMEM((1,), jnp.int32)],
     )
@@ -204,14 +217,15 @@ def flash_decode_paged_pallas(q: jnp.ndarray,           # [B, H, dh]
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(lengths, page_table.astype(jnp.int32).reshape(-1),
-      q.reshape(B, Hk, G, dh), k_pool.reshape(P, ps, Hk * dh),
-      v_pool.reshape(P, ps, Hk * dh))
+      jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)),
+      q.reshape(B, Hk, G, dh), k_pool, v_pool)
     return merge_heads(o, m, l, H)
 
 
 def flash_decode_paged_op(q: jnp.ndarray,           # [B, 1, H, dh] / [B,H,dh]
-                          k_pool: jnp.ndarray,      # [P, ps, Hk, dh]
+                          k_pool: jnp.ndarray,      # [L, P, ps, Hk·dh]
                           v_pool: jnp.ndarray,
+                          layer,                    # int32 scalar
                           page_table: jnp.ndarray,  # [B, MP] int32
                           cache_len,                # [B] valid prefix length
                           *, scale: Optional[float] = None,
@@ -224,6 +238,6 @@ def flash_decode_paged_op(q: jnp.ndarray,           # [B, 1, H, dh] / [B,H,dh]
     if q.ndim == 4:
         q = q[:, 0]
     lengths = valid_span(q.shape[0], cache_len)[:, 1]
-    return flash_decode_paged_pallas(q, k_pool, v_pool, page_table, lengths,
-                                     scale=scale, softcap=softcap,
+    return flash_decode_paged_pallas(q, k_pool, v_pool, layer, page_table,
+                                     lengths, scale=scale, softcap=softcap,
                                      interpret=interpret)

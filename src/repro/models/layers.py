@@ -331,36 +331,41 @@ def attention_decode_paged(
     x: jnp.ndarray,                       # [S, 1, D] new token features
     spec: AttnSpec,
     xcfg: ExchangeConfig,
-    cache: Dict[str, jnp.ndarray],        # {"k": [P,ps,Hk,hd], "v": ...}
+    pool: Dict[str, jnp.ndarray],         # {"k": [L,P,ps,Hk·hd], "v": ...}
+    layer,                                # int32 scalar — this layer
     page_table: jnp.ndarray,              # [S, max_pages] int32
     lengths: jnp.ndarray,                 # [S] int32 — per-row write position
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """One autoregressive step against a shared *paged* KV pool.
+    """One autoregressive step of layer ``layer`` against a shared *paged*
+    KV pool, every layer's pages stacked, read and written where it lies.
 
     Unlike ``attention_decode`` (scalar ``cache_index``, dense per-request
     cache, vmapped per row by the serving chunk), all rows step together
     here — the pool is shared state, so per-row vmap would fork it.  Row
     ``b`` writes its new K/V at logical position ``lengths[b]``, which the
-    page table resolves to physical ``(page_table[b, len//ps], len % ps)``;
-    attention then reads through ``kdsp.decode_attention_paged``.  Rows never
-    write into shared (refcount > 1) pages: the allocator COW-copies any
-    partially-filled shared page at admit, so a row's write frontier always
-    lands in a page it exclusively owns (or the trash page, for idle rows).
+    page table resolves to ``(layer, page_table[b, len//ps], len % ps)`` of
+    the stack, in one scatter; attention then reads the layer's pages
+    through ``kdsp.decode_attention_paged``.  Rows never write into shared
+    (refcount > 1) pages: the allocator COW-copies any partially-filled
+    shared page at admit, so a row's write frontier always lands in a page
+    it exclusively owns (or the trash page, for idle rows).
     """
     if spec.window is not None:
         raise NotImplementedError("paged decode has no sliding-window path")
     B = x.shape[0]
     pos = lengths[:, None].astype(jnp.int32)                  # [S, 1]
     q, k_new, v_new = project_qkv(params, x, spec, pos)
-    ps = cache["k"].shape[1]
+    ps = pool["k"].shape[2]
     blk = (lengths // ps).astype(jnp.int32)
     wp = jnp.take_along_axis(page_table, blk[:, None], axis=1)[:, 0]  # [S]
     off = lengths % ps
-    k_pool = cache["k"].at[wp, off].set(k_new[:, 0].astype(cache["k"].dtype))
-    v_pool = cache["v"].at[wp, off].set(v_new[:, 0].astype(cache["v"].dtype))
+    k_pool = pool["k"].at[layer, wp, off].set(
+        k_new[:, 0].reshape(B, -1).astype(pool["k"].dtype))
+    v_pool = pool["v"].at[layer, wp, off].set(
+        v_new[:, 0].reshape(B, -1).astype(pool["v"].dtype))
     from repro.kernels import dispatch as kdsp
     out = kdsp.decode_attention_paged(
-        q, k_pool, v_pool, page_table, lengths + 1,
+        q, k_pool, v_pool, layer, page_table, lengths + 1,
         logit_softcap=spec.logit_softcap, scale=spec.scale)
     y = out.reshape(B, 1, spec.n_heads * spec.head_dim) @ params["wo"]
     return y, {"k": k_pool, "v": v_pool}

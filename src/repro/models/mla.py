@@ -163,16 +163,17 @@ def mla_decode(params: Params, x: jnp.ndarray, n_heads: int, cfg: MLACfg,
 
 
 def mla_decode_paged(params: Params, x: jnp.ndarray, n_heads: int,
-                     cfg: MLACfg, cache: Dict[str, jnp.ndarray],
+                     cfg: MLACfg, pool: Dict[str, jnp.ndarray], layer,
                      page_table: jnp.ndarray, lengths: jnp.ndarray, *,
                      rope_theta: float = 10000.0, rope_scaling=None
                      ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """Absorbed-form decode of every row against the shared *paged* latent
-    pool (``{"c_kv": [P, ps, r], "k_pe": [P, ps, dr padded]}``): row ``b`` writes
-    its new ``c_kv``/``k_pe`` at logical position ``lengths[b]`` through
-    its page-table row, then its absorbed query scores every head against
-    the row's latent pages (``kdsp.decode_attention_latent_paged``);
-    ``W_uv`` and ``wo`` run after the kernel, as in ``mla_decode``."""
+    """Absorbed-form decode of every row against layer ``layer`` of the
+    shared *paged* latent pool (``{"c_kv": [L, P, ps, r], "k_pe": [L, P,
+    ps, dr padded]}``): row ``b`` writes its new ``c_kv``/``k_pe`` at
+    logical position ``lengths[b]`` through its page-table row, then its
+    absorbed query scores every head against the row's latent pages
+    (``kdsp.decode_attention_latent_paged``); ``W_uv`` and ``wo`` run
+    after the kernel, as in ``mla_decode``."""
     from repro.kernels import dispatch as kdsp
     B = x.shape[0]
     H = n_heads
@@ -181,18 +182,18 @@ def mla_decode_paged(params: Params, x: jnp.ndarray, n_heads: int,
     q_nope, q_pe = q[..., :cfg.qk_nope_dim], q[..., cfg.qk_nope_dim:]
     c_new, pe_new = _project_kv_latent(params, x, cfg, pos, rope_theta,
                                        rope_scaling)
-    ps = cache["c_kv"].shape[1]
+    ps = pool["c_kv"].shape[2]
     wp = jnp.take_along_axis(page_table, (lengths // ps)[:, None].astype(
         jnp.int32), axis=1)[:, 0]                                 # [S]
     off = lengths % ps
-    c_pool = cache["c_kv"].at[wp, off].set(
-        c_new[:, 0].astype(cache["c_kv"].dtype))
-    pe_pool = cache["k_pe"].at[wp, off].set(
-        pad_lanes(pe_new[:, 0], cache["k_pe"].shape[-1]).astype(
-            cache["k_pe"].dtype))
+    c_pool = pool["c_kv"].at[layer, wp, off].set(
+        c_new[:, 0].astype(pool["c_kv"].dtype))
+    pe_pool = pool["k_pe"].at[layer, wp, off].set(
+        pad_lanes(pe_new[:, 0], pool["k_pe"].shape[-1]).astype(
+            pool["k_pe"].dtype))
     q_lat = jnp.einsum("bqhd,rhd->bqhr", q_nope, params["w_uk"])
     o_lat = kdsp.decode_attention_latent_paged(
-        q_lat, q_pe, c_pool, pe_pool, page_table, lengths + 1,
+        q_lat, q_pe, c_pool, pe_pool, layer, page_table, lengths + 1,
         scale=softmax_scale(cfg, rope_scaling))
     out = jnp.einsum("bqhr,rhd->bqhd", o_lat, params["w_uv"])
     y = out.reshape(B, 1, H * cfg.v_head_dim) @ params["wo"]

@@ -620,6 +620,39 @@ def _scan_decode_layers(body_fn, x, params_stack, cache_stack,
     return x, cache_stack
 
 
+def _scan_paged_layers(body_fn, x, params_stack, pool_stack):
+    """Layer scan of paged decode with the stacked page pool in the CARRY.
+
+    ``body_fn(x, layer_params, pool, layer) → (x, pool, per-layer
+    output)``: each layer writes its rows' new entries into the stacked
+    pool and its kernel reads them there, the layer index a coordinate of
+    both.  K/V pages go whole: no layer's pages are sliced out, relaid out
+    or written back (``_scan_decode_layers`` does that per layer, a copy of
+    the layer's whole pool for a one-token write).  Latent pages (a
+    ``c_kv`` leaf) keep that per-layer slice and write-back: body_fn gets
+    the layer's pages as a one-layer stack and layer 0: read in place,
+    two of the held experts' three weight slices beside them lose their
+    memory placement (``tests/test_tpu_compile.py``, PERF.md).  Returns
+    (x, pool, stacked per-layer outputs).
+    """
+    import jax.tree_util as jtu
+    if "c_kv" in pool_stack:
+        def one_layer(xc, lp, c):
+            y, c, out = body_fn(xc, lp, jtu.tree_map(lambda t: t[None], c), 0)
+            return y, jtu.tree_map(lambda t: t[0], c), out
+        return _scan_decode_layers(one_layer, x, params_stack, pool_stack,
+                                   with_ys=True)
+
+    def body(carry, lp):
+        xc, pool, i = carry
+        y, pool, out = body_fn(xc, lp, pool, i)
+        return (y, pool, i + 1), out
+
+    (x, pool_stack, _), ys = jax.lax.scan(
+        body, (x, pool_stack, jnp.asarray(0, jnp.int32)), params_stack)
+    return x, pool_stack, ys
+
+
 def _zeros_in_memory(tree):
     """Keep a zero-filled cache's zeros.  Made inside a program and filled
     by the prefill's layer scan only up to the prompt, the cache is
@@ -633,13 +666,16 @@ def init_decode_cache(cfg: ModelConfig, batch: int, seq: int,
                       paged: bool = False) -> Params:
     """Cache pytree with stacked leading layer/group dims (scan layout).
     ``paged``: the page pool's layout (batch = pages, seq = page size),
-    which pads a latent cache's ``k_pe`` to whole lanes."""
+    which holds a K/V page as [ps, Hk·dh] rows, the paged kernel's layout,
+    and pads a latent cache's ``k_pe`` to whole lanes."""
     dtype = cfg.jdtype
     fam = cfg.family
 
     def kv(n, s):
         c = init_kv_cache(batch, s, cfg.n_kv_heads, cfg.hd, dtype,
                           quant=cfg.kv_quant)
+        if paged:
+            c = {name: t.reshape(batch, s, -1) for name, t in c.items()}
         return _zeros_in_memory(
             jax.tree_util.tree_map(lambda l: jnp.stack([l] * n), c))
 
@@ -805,12 +841,13 @@ def decode_step(params: Params, batch: Dict[str, jnp.ndarray], cache: Params,
 
 
 def _apply_attn_mlp_decode_paged(p: Params, x, cfg: ModelConfig, xcfg,
-                                 spec: AttnSpec, cache, page_table, lengths):
+                                 spec: AttnSpec, pool, layer, page_table,
+                                 lengths):
     """Pre-norm block around ``attention_decode_paged`` — the paged twin of
     ``_apply_attn_mlp_decode`` (identical residual/norm/MLP math)."""
-    h, new_cache = attention_decode_paged(
+    h, new_pool = attention_decode_paged(
         p["attn"], apply_norm(cfg.norm_type, p["ln1"], x), spec, xcfg,
-        cache, page_table, lengths)
+        pool, layer, page_table, lengths)
     if cfg.post_norms:
         h = apply_norm(cfg.norm_type, p["post_attn"], h)
     x = x + h
@@ -818,26 +855,28 @@ def _apply_attn_mlp_decode_paged(p: Params, x, cfg: ModelConfig, xcfg,
     h2 = apply_mlp(p["mlp"], hin, cfg.act)
     if cfg.post_norms:
         h2 = apply_norm(cfg.norm_type, p["post_mlp"], h2)
-    return x + h2, new_cache
+    return x + h2, new_pool
 
 
-def _moe_layer_decode_paged(p: Params, x, cfg: ModelConfig, xcfg, cache,
-                            page_table, lengths, dense_mlp: bool):
-    """One MoE-family layer stepped against its paged pool slice: latent
-    pages (MLA) or K/V pages, then the dense or held-experts MLP →
-    (x, new cache, tokens each held expert computed or None)."""
+def _moe_layer_decode_paged(p: Params, x, cfg: ModelConfig, xcfg, pool,
+                            layer, page_table, lengths, dense_mlp: bool):
+    """One MoE-family layer stepped against layer ``layer`` of its stacked
+    paged pool: latent pages (MLA) or K/V pages, then the dense or
+    held-experts MLP → (x, new pool, tokens each held expert computed or
+    None)."""
     xin = apply_norm(cfg.norm_type, p["ln1"], x)
     if cfg.mla is not None:
-        h, nc = mla_mod.mla_decode_paged(
-            p["attn"], xin, cfg.n_heads, cfg.mla, cache, page_table,
+        h, pool = mla_mod.mla_decode_paged(
+            p["attn"], xin, cfg.n_heads, cfg.mla, pool, layer, page_table,
             lengths, rope_theta=cfg.rope_theta,
             rope_scaling=cfg.rope_scaling)
     else:
-        h, nc = attention_decode_paged(p["attn"], xin, _attn_spec(cfg), xcfg,
-                                       cache, page_table, lengths)
+        h, pool = attention_decode_paged(p["attn"], xin, _attn_spec(cfg),
+                                         xcfg, pool, layer, page_table,
+                                         lengths)
     x = x + h
     y, tokens = _moe_mlp(p, x, cfg, dense_mlp)
-    return x + y, nc, tokens
+    return x + y, pool, tokens
 
 
 def supports_page_pool(cfg: ModelConfig) -> bool:
@@ -854,7 +893,8 @@ def supports_page_pool(cfg: ModelConfig) -> bool:
 def init_page_pool(cfg: ModelConfig, n_pages: int, page_size: int) -> Params:
     """Shared paged pool: same pytree as ``init_decode_cache`` but the
     (batch, seq) axes become (page, in-page position) — leaves are
-    ``[n_layers, n_pages, page_size, Hk, dh]`` (K and V), or under MLA
+    ``[n_layers, n_pages, page_size, Hk·dh]`` (K and V, a page's rows in
+    the layout the paged kernel copies), or under MLA
     ``[n_layers, n_pages, page_size, r]`` (``c_kv``) and ``[..., 128]``
     (``k_pe``, dr of them used, the rest zeros: ``mla.PE_LANES``).
     Requests address it through per-row page tables; physical rows are
@@ -885,22 +925,25 @@ def decode_step_paged(params: Params, batch: Dict[str, jnp.ndarray],
     counts = None
     if cfg.family == "moe":
         def make_body(dense_mlp):
-            def body(xc, lp, c):
-                return _moe_layer_decode_paged(lp, xc, cfg, xcfg, c,
-                                               page_table, lengths, dense_mlp)
+            def body(xc, lp, stack, layer):
+                return _moe_layer_decode_paged(lp, xc, cfg, xcfg, stack,
+                                               layer, page_table, lengths,
+                                               dense_mlp)
             return body
-        x, nfirst = _scan_decode_layers(make_body(True), x,
-                                        params["first_layers"], pool["first"])
-        x, nkv, counts = _scan_decode_layers(make_body(False), x,
-                                             params["layers"], pool["kv"],
-                                             with_ys=True)
+        x, nfirst, _ = _scan_paged_layers(make_body(True), x,
+                                          params["first_layers"],
+                                          pool["first"])
+        x, nkv, counts = _scan_paged_layers(make_body(False), x,
+                                            params["layers"], pool["kv"])
         new_pool = {"first": nfirst, "kv": nkv}
     else:
-        def body(xc, lp, c):
-            return _apply_attn_mlp_decode_paged(lp, xc, cfg, xcfg,
-                                                _attn_spec(cfg), c,
-                                                page_table, lengths)
-        x, nkv = _scan_decode_layers(body, x, params["layers"], pool["kv"])
+        def body(xc, lp, stack, layer):
+            x, stack = _apply_attn_mlp_decode_paged(lp, xc, cfg, xcfg,
+                                                    _attn_spec(cfg), stack,
+                                                    layer, page_table,
+                                                    lengths)
+            return x, stack, None
+        x, nkv, _ = _scan_paged_layers(body, x, params["layers"], pool["kv"])
         new_pool = {"kv": nkv}
     x = apply_norm(cfg.norm_type, params["final_norm"], x)
     head = params["embed"] if cfg.tie_embeddings else params["lm_head"]

@@ -4,7 +4,7 @@ The dense :class:`~repro.serving.engine.SlotPool` reserves ``max_len``
 positions per slot up front, so a pool sized for long requests strands most
 of its memory on short ones.  This module replaces that with vLLM-style
 paging: one shared device pool of fixed-size KV pages
-(``[n_layers, n_pages, page_size, Hk, dh]`` per leaf), per-request page
+(``[n_layers, n_pages, page_size, Hk·dh]`` per leaf), per-request page
 tables, and a host-side free-list allocator that grows a request's table
 page-by-page as decode advances.  Admission is bounded by *free pages*, not
 free rows, so concurrency at a fixed memory budget scales with actual

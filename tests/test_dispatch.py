@@ -211,9 +211,10 @@ def test_decode_head_dim_the_chip_cannot_tile_falls_back(monkeypatch, paged):
     clen = jnp.asarray([20, 32])
     if paged:
         op, name = kdsp.decode_attention_paged, "decode_attention_paged"
-        k = jnp.asarray(RNG.randn(B * S // ps, ps, Hk, dh), jnp.float32)
-        v = jnp.asarray(RNG.randn(B * S // ps, ps, Hk, dh), jnp.float32)
-        args = (q, k, v, jnp.arange(B * S // ps).reshape(B, S // ps), clen)
+        k = jnp.asarray(RNG.randn(1, B * S // ps, ps, Hk * dh), jnp.float32)
+        v = jnp.asarray(RNG.randn(1, B * S // ps, ps, Hk * dh), jnp.float32)
+        args = (q, k, v, 0, jnp.arange(B * S // ps).reshape(B, S // ps),
+                clen)
     else:
         op, name = kdsp.decode_attention, "decode_attention"
         k = jnp.asarray(RNG.randn(B, S, Hk, dh), jnp.float32)
@@ -237,16 +238,17 @@ def test_paged_decode_one_kv_head_needs_a_lane_multiple(monkeypatch):
     compiled (the dense kernel takes it whole), and is counted."""
     B, ps, MP, H, dh = 2, 8, 4, 4, 64
     q = jnp.asarray(RNG.randn(B, 1, H, dh), jnp.float32)
-    k = jnp.asarray(RNG.randn(B * MP, ps, 1, dh), jnp.float32)
-    v = jnp.asarray(RNG.randn(B * MP, ps, 1, dh), jnp.float32)
+    k = jnp.asarray(RNG.randn(1, B * MP, ps, dh), jnp.float32)
+    v = jnp.asarray(RNG.randn(1, B * MP, ps, dh), jnp.float32)
     pt = jnp.arange(B * MP).reshape(B, MP)
     clen = jnp.asarray([9, 32])
     monkeypatch.setattr(kdsp, "_interpret", lambda: False)
-    assert kdsp._decode_tiles(k) and not kdsp._decode_tiles(k, True)
+    heads = k.reshape(B * MP, ps, 1, dh)
+    assert kdsp._decode_tiles(heads) and not kdsp._decode_tiles(heads, True)
     before = _fallbacks("decode_attention_paged/head_dim")
     with kdsp.force_backend("pallas"):
-        got = kdsp.decode_attention_paged(q, k, v, pt, clen)
+        got = kdsp.decode_attention_paged(q, k, v, 0, pt, clen)
     assert _fallbacks("decode_attention_paged/head_dim") == before + 1
     with kdsp.force_backend("reference"):
-        want = kdsp.decode_attention_paged(q, k, v, pt, clen)
+        want = kdsp.decode_attention_paged(q, k, v, 0, pt, clen)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -158,14 +158,14 @@ def test_flash_decode_paged_ignores_garbage_outside_valid_slots():
                    jnp.arange(B * MP).reshape(B, MP), B * MP)
     ok = jnp.concatenate([
         validity_mask(B, MP * ps, clen).reshape(B * MP, ps),
-        jnp.zeros((1, ps), bool)])[..., None, None]
-    k = jnp.asarray(RNG.randn(B * MP + 1, ps, Hk, dh), jnp.float32)
-    v = jnp.asarray(RNG.randn(B * MP + 1, ps, Hk, dh), jnp.float32)
+        jnp.zeros((1, ps), bool)])[None, ..., None]
+    k = jnp.asarray(RNG.randn(1, B * MP + 1, ps, Hk * dh), jnp.float32)
+    v = jnp.asarray(RNG.randn(1, B * MP + 1, ps, Hk * dh), jnp.float32)
     clean = flash_decode_paged_op(q, jnp.where(ok, k, 0.0),
-                                  jnp.where(ok, v, 0.0), pt, clen,
+                                  jnp.where(ok, v, 0.0), 0, pt, clen,
                                   interpret=True)
     dirty = flash_decode_paged_op(q, jnp.where(ok, k, jnp.nan),
-                                  jnp.where(ok, v, jnp.nan), pt, clen,
+                                  jnp.where(ok, v, jnp.nan), 0, pt, clen,
                                   interpret=True)
     for a, b in zip(dirty, clean):
         assert np.all(np.isfinite(np.asarray(a)))
@@ -211,20 +211,21 @@ def _paged_case(*shape, lens="random"):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_decode_paged_matches_gather_reference(B, P, ps, MP, H, Hk,
                                                      dh, lens, dtype):
-    """Pallas paged kernel (page table and lengths through scalar
+    """Pallas paged kernel (page table, lengths and layer through scalar
     prefetch, pages copied a block at a time) vs the jnp.take gather +
-    dense reference, on random page tables with repeated pages.  Lengths:
-    ragged at random; or ``edges``, one row each of 1, one block, one
-    block + 1 and the whole table; or ``trash``, a row whose whole table
-    points at one page, as an idle row's points at the trash page, beside
-    a row whose length runs past its table."""
+    dense reference, on random page tables with repeated pages, reading
+    the second layer of a two-layer stacked pool.  Lengths: ragged at
+    random; or ``edges``, one row each of 1, one block, one block + 1 and
+    the whole table; or ``trash``, a row whose whole table points at one
+    page, as an idle row's points at the trash page, beside a row whose
+    length runs past its table."""
     from repro.kernels.flash_decode import (flash_decode_paged_op,
                                             flash_decode_paged_ref,
                                             gather_pages)
     from repro.kernels.flash_decode.paged import pages_per_block
     q = jnp.asarray(RNG.randn(B, H, dh), dtype)
-    kp = jnp.asarray(RNG.randn(P, ps, Hk, dh), dtype)
-    vp = jnp.asarray(RNG.randn(P, ps, Hk, dh), dtype)
+    kp = jnp.asarray(RNG.randn(2, P, ps, Hk * dh), dtype)
+    vp = jnp.asarray(RNG.randn(2, P, ps, Hk * dh), dtype)
     pt = RNG.randint(0, P, size=(B, MP))
     clen = RNG.randint(1, MP * ps + 1, size=B)
     if lens == "edges":
@@ -236,14 +237,95 @@ def test_flash_decode_paged_matches_gather_reference(B, P, ps, MP, H, Hk,
         clen[0] = MP * ps + 7
     pt, clen = jnp.asarray(pt, jnp.int32), jnp.asarray(clen)
     bias = validity_bias(B, MP * ps, clen)
-    o, m, l = flash_decode_paged_op(q, kp, vp, pt, clen, interpret=True)
-    orf, mrf, lrf = flash_decode_paged_ref(q, kp, vp, pt, bias)
+    o, m, l = flash_decode_paged_op(q, kp, vp, 1, pt, clen, interpret=True)
+    orf, mrf, lrf = flash_decode_paged_ref(q, kp, vp, 1, pt, bias)
     np.testing.assert_allclose(np.asarray(o, np.float32),
                                np.asarray(orf, np.float32), **_tol(dtype))
     np.testing.assert_allclose(np.asarray(l), np.asarray(lrf),
                                **_tol(dtype))
-    # and the gather itself is the dense layout the dense op sees
-    assert gather_pages(kp, pt).shape == (B, MP * ps, Hk, dh)
+    # and the gather itself is one layer's pages, row after row
+    got = gather_pages(kp, 1, pt)
+    assert got.shape == (B, MP * ps, Hk * dh)
+    np.testing.assert_array_equal(
+        np.asarray(got[0, ps:2 * ps], np.float32),
+        np.asarray(kp[1, pt[0, 1]], np.float32))
+
+
+# a three-layer stacked pool at internlm2-1.8b's K/V widths: rows ending
+# exactly on a page boundary, one page past it, and an idle row whose
+# table is all trash page (the pool's last)
+_STACK = dict(L=3, P=13, ps=16, MP=4, H=16, Hk=8, dh=128)
+
+
+def _stacked_case(dtype):
+    L, P, ps, MP, H, Hk, dh = (_STACK[k] for k in
+                               ("L", "P", "ps", "MP", "H", "Hk", "dh"))
+    q = jnp.asarray(RNG.randn(4, H, dh), dtype)
+    kp = jnp.asarray(RNG.randn(L, P, ps, Hk * dh), dtype)
+    vp = jnp.asarray(RNG.randn(L, P, ps, Hk * dh), dtype)
+    pt = jnp.asarray([[0, 1, 2, 3], [4, 5, 6, 7], [8, 9, 10, 11],
+                      [P - 1] * MP], jnp.int32)
+    clen = jnp.asarray([ps, 2 * ps, 2 * ps + 1, 1])
+    return q, kp, vp, pt, clen
+
+
+@pytest.mark.parametrize("layer", [0, _STACK["L"] - 1])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_decode_paged_reads_only_its_layer(layer, dtype):
+    """Through the stacked pool, the kernel (interpret mode) reads the
+    pages of the layer it is given: it equals the gather reference of
+    that layer, for the first and the last layer, with rows ending on a
+    page boundary and an idle row on the trash page; and NaN in every
+    other layer's pages, the same page ids, changes none of its output."""
+    from repro.kernels.flash_decode import (flash_decode_paged_op,
+                                            flash_decode_paged_ref)
+    q, kp, vp, pt, clen = _stacked_case(dtype)
+    got = flash_decode_paged_op(q, kp, vp, layer, pt, clen, interpret=True)
+    want = flash_decode_paged_ref(
+        q, kp, vp, layer, pt, validity_bias(4, _STACK["MP"] * _STACK["ps"],
+                                            clen))
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), **_tol(dtype))
+    others = jnp.arange(_STACK["L"])[:, None, None, None] != layer
+    poisoned = flash_decode_paged_op(q, jnp.where(others, jnp.nan, kp),
+                                     jnp.where(others, jnp.nan, vp), layer,
+                                     pt, clen, interpret=True)
+    for a, b in zip(poisoned, got):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("backend", ["reference", "pallas"])
+def test_paged_step_writes_one_entry_a_row_in_its_layer(backend):
+    """One paged attention step of layer 1 of a three-layer stacked pool:
+    each row's new K and V land at (layer, its page, its offset) — the
+    next page's first slot for a row ending on a page boundary, the trash
+    page for the idle row — and nothing else in the stack changes; the
+    output is the one a lone copy of that layer's pages gives."""
+    from repro.kernels import dispatch as kdsp
+    from repro.models.layers import (AttnSpec, attention_decode_paged,
+                                     init_attention)
+    L, P, ps, H, Hk, dh = (_STACK[k] for k in
+                           ("L", "P", "ps", "H", "Hk", "dh"))
+    _, kp, vp, pt, clen = _stacked_case(jnp.float32)
+    spec = AttnSpec(n_heads=H, n_kv=Hk, head_dim=dh)
+    params = init_attention(jax.random.key(3), 64, H, Hk, dh, jnp.float32)
+    x = jnp.asarray(RNG.randn(4, 1, 64), jnp.float32)
+    pool = {"k": kp, "v": vp}
+    with kdsp.force_backend(backend):
+        y, new = attention_decode_paged(params, x, spec, None, pool, 1, pt,
+                                        clen)
+        y1, _ = attention_decode_paged(
+            params, x, spec, None, {n: t[1:2] for n, t in pool.items()}, 0,
+            pt, clen)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y1), atol=1e-6)
+    pages = np.asarray(pt)[np.arange(4), np.asarray(clen) // ps]
+    assert pages.tolist() == [1, 6, 10, P - 1]
+    for name in ("k", "v"):
+        changed = np.argwhere(np.any(np.asarray(new[name])
+                                     != np.asarray(pool[name]), axis=-1))
+        assert sorted(map(tuple, changed.tolist())) == sorted(
+            (1, int(p), int(n) % ps) for p, n in zip(pages, clen))
 
 
 def test_flash_decode_paged_softcap_and_normalized():
@@ -253,14 +335,16 @@ def test_flash_decode_paged_softcap_and_normalized():
                                             gather_pages)
     B, P, ps, MP, H, dh = 2, 7, 16, 3, 4, 16
     q = jnp.asarray(RNG.randn(B, H, dh), jnp.float32)
-    kp = jnp.asarray(RNG.randn(P, ps, H, dh), jnp.float32)
-    vp = jnp.asarray(RNG.randn(P, ps, H, dh), jnp.float32)
+    kp = jnp.asarray(RNG.randn(1, P, ps, H * dh), jnp.float32)
+    vp = jnp.asarray(RNG.randn(1, P, ps, H * dh), jnp.float32)
     pt = jnp.asarray(RNG.randint(0, P, size=(B, MP)), jnp.int32)
     clen = jnp.asarray([17, 40])
-    o, m, l = flash_decode_paged_op(q, kp, vp, pt, clen, softcap=30.0,
+    o, m, l = flash_decode_paged_op(q, kp, vp, 0, pt, clen, softcap=30.0,
                                     interpret=True)
-    od, md, ld = flash_decode_op(q, gather_pages(kp, pt),
-                                 gather_pages(vp, pt), clen, softcap=30.0)
+    dense = (B, MP * ps, H, dh)
+    od, md, ld = flash_decode_op(q, gather_pages(kp, 0, pt).reshape(dense),
+                                 gather_pages(vp, 0, pt).reshape(dense),
+                                 clen, softcap=30.0)
     np.testing.assert_allclose(np.asarray(o / l[..., None]),
                                np.asarray(od / ld[..., None]), atol=3e-5)
 
@@ -271,14 +355,14 @@ def test_paged_dispatch_backend_parity():
     from repro.kernels import dispatch as kdsp
     B, P, ps, MP, H, dh = 2, 6, 8, 3, 2, 16
     q = jnp.asarray(RNG.randn(B, 1, H, dh), jnp.float32)
-    kp = jnp.asarray(RNG.randn(P, ps, H, dh), jnp.float32)
-    vp = jnp.asarray(RNG.randn(P, ps, H, dh), jnp.float32)
+    kp = jnp.asarray(RNG.randn(3, P, ps, H * dh), jnp.float32)
+    vp = jnp.asarray(RNG.randn(3, P, ps, H * dh), jnp.float32)
     pt = jnp.asarray(RNG.randint(0, P, size=(B, MP)), jnp.int32)
     clen = jnp.asarray([5, 20])
     with kdsp.force_backend("pallas"):
-        a = kdsp.decode_attention_paged(q, kp, vp, pt, clen)
+        a = kdsp.decode_attention_paged(q, kp, vp, 2, pt, clen)
     with kdsp.force_backend("reference"):
-        b = kdsp.decode_attention_paged(q, kp, vp, pt, clen)
+        b = kdsp.decode_attention_paged(q, kp, vp, 2, pt, clen)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-5)
 
 

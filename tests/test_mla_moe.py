@@ -230,7 +230,8 @@ def test_latent_kernel_matches_reference(monkeypatch, B, ps, block, lengths):
             pe[pt[b, p], lo:] = np.nan
     args = (jnp.asarray(rng.randn(B, H, r), jnp.bfloat16),
             jnp.asarray(rng.randn(B, H, dr), jnp.bfloat16),
-            jnp.asarray(c, jnp.bfloat16), jnp.asarray(pe, jnp.bfloat16),
+            jnp.asarray(c[None], jnp.bfloat16),
+            jnp.asarray(pe[None], jnp.bfloat16), 0,
             jnp.asarray(pt, jnp.int32), jnp.asarray(lengths, jnp.int32))
     o, m, l = latent.flash_decode_latent_pallas(*args, scale=0.07,
                                                 interpret=True)
@@ -246,21 +247,22 @@ def test_latent_dispatch_routes_the_kernel_or_the_reference():
     rng = np.random.RandomState(0)
     q = jnp.asarray(rng.randn(2, 1, 4, 128), jnp.bfloat16)
     qp = jnp.asarray(rng.randn(2, 1, 4, 8), jnp.bfloat16)
-    c = jnp.asarray(rng.randn(6, 8, 128), jnp.bfloat16)
-    pe = jnp.asarray(np.pad(rng.randn(6, 8, 8), ((0, 0), (0, 0), (0, 120))),
+    c = jnp.asarray(rng.randn(2, 6, 8, 128), jnp.bfloat16)
+    pe = jnp.asarray(np.pad(rng.randn(2, 6, 8, 8),
+                            ((0, 0), (0, 0), (0, 0), (0, 120))),
                      jnp.bfloat16)
     pt = jnp.asarray([[0, 2, 4], [1, 3, 5]], jnp.int32)
     n = jnp.asarray([20, 3], jnp.int32)
     with kdsp.force_backend("reference"):
-        ref = kdsp.decode_attention_latent_paged(q, qp, c, pe, pt, n,
+        ref = kdsp.decode_attention_latent_paged(q, qp, c, pe, 1, pt, n,
                                                  scale=0.1)
     with kdsp.force_backend("pallas"):
-        got = kdsp.decode_attention_latent_paged(q, qp, c, pe, pt, n,
+        got = kdsp.decode_attention_latent_paged(q, qp, c, pe, 1, pt, n,
                                                  scale=0.1)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(ref, np.float32), atol=1e-2)
-    assert kdsp.paged_pages_per_block({"kv": {"c_kv": c[None],
-                                              "k_pe": pe[None]}}, 3) == 3
+    assert kdsp.paged_pages_per_block({"kv": {"c_kv": c,
+                                              "k_pe": pe}}, 3) == 3
 
 
 # --- MoE models through the paged pool ----------------------------------

@@ -183,8 +183,8 @@ def test_paged_chunk_counts_kernel_blocks(session, monkeypatch):
         if len(want) < rt.stats["chunks"]:
             pool = next(iter(rt.pools.values()))
             k = pool.pool["kv"]["k"]
-            ppb = paged.pages_per_block(8, k.shape[-2] * k.shape[-1],
-                                        pool.max_pages, k.dtype.itemsize)
+            ppb = paged.pages_per_block(8, k.shape[-1], pool.max_pages,
+                                        k.dtype.itemsize)
             assert ppb == 2 < pool.max_pages
             lens = np.asarray(pool.lengths)
             want.append((int(sum(-(-lens // (ppb * 8)))),

@@ -7,8 +7,12 @@ and the prefill, for what only the TPU compiler does to it.
 
 Widths: internlm2-1.8b (H=16, Hk=8, dh=128, caches up to 4096 positions,
 16-position pages), DeepSeek-V2-Lite's latent pages (H=16, r=512, rope 64)
-and ViT-B/16 (197 tokens padded, d=768, 12 heads).
+and ViT-B/16 (197 tokens padded, d=768, 12 heads).  Two compile paged
+decode programs, to see what the compiler made of the stacked page pool.
 """
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -65,11 +69,12 @@ def test_flash_decode_compiles(one_chip, S):
 def test_paged_flash_decode_compiles(one_chip, page_size, B):
     H, Hk, dh, pages_per_row = 16, 8, 128, 128
     n_pages = B * pages_per_row + 1
-    pool = _shape(one_chip, (n_pages, page_size, Hk, dh))
+    pool = _shape(one_chip, (2, n_pages, page_size, Hk * dh))
     text = _compile(
-        lambda q, k, v, pt, n: flash_decode_paged_op(q, k, v, pt, n,
-                                                     interpret=False),
+        lambda q, k, v, layer, pt, n: flash_decode_paged_op(
+            q, k, v, layer, pt, n, interpret=False),
         _shape(one_chip, (B, 1, H, dh)), pool, pool,
+        _shape(one_chip, (), jnp.int32),
         _shape(one_chip, (B, pages_per_row), jnp.int32),
         _shape(one_chip, (B,), jnp.int32))
     assert "tpu_custom_call" in text
@@ -82,12 +87,84 @@ def test_paged_latent_decode_compiles(one_chip, B):
     H, r, dr, dp, ps, MP = 16, 512, 64, 128, 16, 192
     n_pages = 4096 + 1
     text = _compile(
-        lambda q, qp, c, pe, pt, n: flash_decode_latent_pallas(
-            q, qp, c, pe, pt, n, scale=0.1, interpret=False),
+        lambda q, qp, c, pe, layer, pt, n: flash_decode_latent_pallas(
+            q, qp, c, pe, layer, pt, n, scale=0.1, interpret=False),
         _shape(one_chip, (B, H, r)), _shape(one_chip, (B, H, dr)),
-        _shape(one_chip, (n_pages, ps, r)), _shape(one_chip, (n_pages, ps, dp)),
+        _shape(one_chip, (2, n_pages, ps, r)),
+        _shape(one_chip, (2, n_pages, ps, dp)),
+        _shape(one_chip, (), jnp.int32),
         _shape(one_chip, (B, MP), jnp.int32), _shape(one_chip, (B,), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+def _paged_program_text(chip, monkeypatch, cfg, n_pages, program):
+    """Compiled text of a paged program over ``cfg``'s stacked pool of
+    ``n_pages`` pages of 16 (+ the trash page), its kernels compiled:
+    ``chunk``, a decode chunk of 2 steps over 8 rows of 128-page tables
+    (32 rows of 192 pages under MLA, DeepSeek-V2-Lite's cell), or
+    ``suffix``, a 4-token suffix scan of one row."""
+    from repro.api import ExecutionPlan, generation
+    from repro.kernels import dispatch as kdsp
+    from repro.models import registry
+    from repro.models import transformer as tfm
+    monkeypatch.setattr(kdsp, "_interpret", lambda: False)
+    rows, MP = (32, 192) if cfg.mla is not None else (8, 128)
+    xcfg = ExecutionPlan.local().to_exchange_config()
+    shaped = lambda t: jax.tree_util.tree_map(
+        lambda a: _shape(chip, a.shape, a.dtype), t)
+    params = shaped(registry.abstract_params(cfg))
+    pool = shaped(jax.eval_shape(
+        lambda: tfm.init_page_pool(cfg, n_pages + 1, 16)))
+    i32 = lambda *s: _shape(chip, s, jnp.int32)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    if program == "chunk":
+        fn = generation.build_paged_decode_chunk_fn(cfg, xcfg, n_steps=2)
+        keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0),
+                                                       rows))
+        args = (params, pool, i32(rows, MP), i32(rows), i32(rows),
+                i32(rows), shaped(keys),
+                _shape(chip, (rows,), jnp.float32))
+    else:
+        fn = generation.build_paged_suffix_fn(cfg, xcfg, n_suffix=4)
+        args = (params, pool, i32(1, MP), i32(1, 4), i32(1), shaped(key),
+                _shape(chip, (), jnp.float32))
+    with kdsp.force_backend("pallas"):
+        return fn.jitted.lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("program", ["chunk", "suffix"])
+def test_paged_decode_reads_the_stacked_pool_in_place(one_chip, monkeypatch,
+                                                      program):
+    """internlm2-1.8b's widths, two layers and a pool of 1,536 pages: the
+    paged programs write and read every layer's K/V pages where they lie
+    in the stacked pool.  No op makes one layer's pages, in the pool's
+    layout [P, 16, 1024], the model's [P, 16, 8, 128] or either with a
+    leading 1: no per-layer slice, relayout or write-back."""
+    from repro.configs import get_config
+    cfg = dataclasses.replace(get_config("internlm2-1.8b"), n_layers=2)
+    text = _paged_program_text(one_chip, monkeypatch, cfg, 1536, program)
+    assert "tpu_custom_call" in text
+    assert "bf16[2,1537,16,1024]" in text
+    one_layer = re.compile(r"bf16\[(1,)?1537,16,(1024|8,128)\]")
+    assert not one_layer.search(text), one_layer.search(text).group(0)
+
+
+def test_latent_pages_keep_the_held_experts_placement(one_chip, monkeypatch):
+    """DeepSeek-V2-Lite's decode chunk at its cell's sizes (27 layers, 16
+    of 64 experts held, 4,096 latent pages): each MoE layer stages its
+    held experts' three weight stacks for the grouped matmul, and the
+    compiler gives all three its alternate memory space (``S(1)``).
+    Latent pages therefore keep their per-layer slice: read in place,
+    the w_gate and w_up stacks lose that placement (PERF.md)."""
+    from repro.configs import get_config
+    cfg = get_config("deepseek-v2-lite")
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe,
+                                                           held=(0, 16)))
+    text = _paged_program_text(one_chip, monkeypatch, cfg, 4096, "chunk")
+    staged = re.findall(r"= (bf16\[16,(?:2048,1408|1408,2048)\]\{[^}]*\}) "
+                        r"fusion\(", text)
+    assert len(staged) == 3 and all("S(1)" in s for s in staged), staged
+    assert "bf16[4097,16,512]" in text          # one layer's latent pages
 
 
 @pytest.mark.parametrize("shape, L", [
